@@ -55,12 +55,12 @@ import numpy as np
 
 from repro.core import LotaruEstimator, TickEngine, blr, build_state, \
     get_node, profile_cluster, profile_node, target_nodes
-from repro.core.estimator import FittedTask
-from repro.core.profiler import BenchResult
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_fleet_mesh
 from repro.obs import (EventLog, calibration_summary, observe_records,
                        tick_latency_summary)
-from repro.data.synthetic import synthetic_dag
+from repro.data.synthetic import (scale_estimator, synthetic_dag,
+                                  synthetic_samples)
 from repro.online import OnlineExecutor, fanout_chain_dag
 from repro.online.fleet import fleet_tick_step, shard_fleet, stack_states
 from repro.sched.heft import (CommCosts, heft_schedule_array,
@@ -80,23 +80,8 @@ CAL_BAND = (0.80, 0.98)
 Z90 = 1.6448536269514722     # Phi^-1(0.95): the 90% two-sided z quantile
 
 
-def _synthetic_samples(n_tasks: int, n_samples: int = 8, seed: int = 0):
-    rng = np.random.default_rng(seed)
-    sizes_list, runtimes_list = [], []
-    for i in range(n_tasks):
-        sizes = np.geomspace(1.0, 256.0, n_samples) * rng.uniform(0.5, 2.0)
-        if rng.random() < 0.7:
-            rts = (rng.uniform(0.1, 5.0) * sizes + rng.uniform(1, 50)
-                   + rng.normal(0, 0.05, n_samples))
-        else:
-            rts = rng.uniform(20, 200) + rng.normal(0, 0.5, n_samples)
-        sizes_list.append(sizes)
-        runtimes_list.append(np.abs(rts))
-    return sizes_list, runtimes_list
-
-
 def bench_update_throughput(n_tasks: int = 1000, n_updates: int = 500):
-    sizes_list, runtimes_list = _synthetic_samples(n_tasks)
+    sizes_list, runtimes_list = synthetic_samples(n_tasks)
     model = blr.fit_task_batch(sizes_list, runtimes_list)
 
     # full-refit steady state (the seed's only way to absorb a sample)
@@ -150,7 +135,7 @@ def bench_update_throughput(n_tasks: int = 1000, n_updates: int = 500):
 
 def bench_equivalence(n_tasks: int = 200, per_task: int = 5, seed: int = 2):
     rng = np.random.default_rng(seed)
-    sizes_list, runtimes_list = _synthetic_samples(n_tasks, seed=seed)
+    sizes_list, runtimes_list = synthetic_samples(n_tasks, seed=seed)
     model = blr.fit_task_batch(sizes_list, runtimes_list)
     stream = [(int(rng.integers(0, n_tasks)), float(rng.uniform(1, 400)),
                float(rng.uniform(1, 600)))
@@ -588,42 +573,6 @@ SCALE_POINTS_GATE = [(256, 16), (2048, 50)]
 SCALE_POINTS_FULL = SCALE_POINTS_GATE + [(1024, 64), (4096, 256)]
 
 
-def _scale_bench(name: str, rng) -> BenchResult:
-    return BenchResult(node=name,
-                       cpu_events_s=float(rng.uniform(300.0, 900.0)),
-                       matmul_gflops=float(rng.uniform(50.0, 200.0)),
-                       mem_gbps=float(rng.uniform(10.0, 40.0)),
-                       io_read_mbps=float(rng.uniform(200.0, 800.0)),
-                       io_write_mbps=float(rng.uniform(200.0, 800.0)),
-                       link_gbps=0.0)
-
-
-def _scale_estimator(n_tasks: int, n_nodes: int, seed: int = 0):
-    """A real ``LotaruEstimator`` at arbitrary (T, N): synthetic benches
-    for N nodes, one ``fit_task_batch`` solve for T tasks injected as
-    ``FittedTask``s (the batch cache is primed with the same fit, exactly
-    like ``fit_tasks``) — the paper's five workflows top out at T=14, so
-    the sweep needs shapes the workflow registry cannot provide."""
-    rng = np.random.default_rng(seed)
-    local = _scale_bench("local-cpu", rng)
-    nodes = [f"n{j}" for j in range(n_nodes)]
-    benches = {n: _scale_bench(n, rng) for n in nodes}
-    est = LotaruEstimator(local, benches, bias_correction=True,
-                          bias_empirical_bayes=True)
-    sizes_list, runtimes_list = _synthetic_samples(n_tasks, seed=seed)
-    batch = blr.fit_task_batch(sizes_list, runtimes_list)
-    names = [f"t{i}" for i in range(n_tasks)]
-    ws = rng.uniform(0.2, 0.95, n_tasks)
-    for i, (name, model) in enumerate(zip(names,
-                                          blr.unstack_task_models(batch))):
-        est.tasks[name] = FittedTask(model=model, w=float(ws[i]),
-                                     sizes=np.asarray(sizes_list[i]),
-                                     runtimes=np.asarray(runtimes_list[i]))
-    est._batch_cache = (names, [est.tasks[n] for n in names], batch,
-                        np.asarray(ws, np.float64))
-    return est, names, nodes
-
-
 def _scale_obs(names, nodes, rng, batch: int):
     """One tick's worth of (task, node, size, runtime) observations.
 
@@ -658,7 +607,7 @@ def _scale_point(t: int, n: int, seed: int = 0) -> dict:
             tick(b)
         return (time.perf_counter() - t0) / SCALE_TICKS
 
-    est, _names, nodes = _scale_estimator(t, n, seed=seed)
+    est, _names, nodes = scale_estimator(t, n, seed=seed)
     log_l = EventLog()
     est.set_tracer(log_l)
     est.predict_matrix(nodes, SCALE_SIZE)          # prime cache + compile
@@ -671,7 +620,7 @@ def _scale_point(t: int, n: int, seed: int = 0) -> dict:
     legacy_s = drive(legacy_tick)
     jax.clear_caches()
 
-    est2, _names, nodes = _scale_estimator(t, n, seed=seed)
+    est2, _names, nodes = scale_estimator(t, n, seed=seed)
     log_f = EventLog()
     engine = TickEngine(est2, nodes, size=SCALE_SIZE, tracer=log_f)
 
@@ -693,7 +642,7 @@ def _fleet_point(w: int, t: int, n: int, seed: int = 0) -> dict:
     """Throughput of the vmapped fleet tick over W stacked workflows,
     sharded across whatever devices the mesh exposes when the W axis
     divides (a single device replicates — today's layout)."""
-    est, _names, nodes = _scale_estimator(t, n, seed=seed)
+    est, _names, nodes = scale_estimator(t, n, seed=seed)
     state, _sn = build_state(est, nodes)
     fleet = stack_states([state] * w)
     mesh = make_fleet_mesh(task=1)
@@ -878,6 +827,7 @@ if __name__ == "__main__":
                          "BENCH_online.json write — the CI scheduling "
                          "smoke")
     a = ap.parse_args()
+    enable_compile_cache()
     if a.locality_smoke:
         ls = locality_scale()
         ok = (ls["n_tasks"] >= ls["min_tasks"]

@@ -23,6 +23,7 @@ from repro.core import LotaruEstimator
 from repro.core.blr import fit_task
 from repro.core.estimator import FittedTask
 from repro.core.profiler import BenchResult
+from repro.launch.cache import enable_compile_cache
 from repro.sched.heft import (SchedTask, heft_schedule_array,
                               heft_schedule_reference)
 
@@ -154,4 +155,5 @@ def run(n_tasks: int = 1000, n_nodes: int = 64) -> list[tuple]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

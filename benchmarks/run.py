@@ -8,6 +8,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from . import (calibration, fig4_downsampling, fig5_cdf,
                    fig6_homogeneous, roofline_table, scheduler_e2e,
                    table2_microbench, table45_factors, table6_heterogeneous,

@@ -3,10 +3,8 @@ op_name metadata — the §Perf profiling tool.
 
   PYTHONPATH=src python scripts/probe_collectives.py qwen2-7b train_4k single
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import collections
+import os
 import re
 import sys
 
@@ -41,7 +39,7 @@ def compile_cell(arch, shape_name, multi_pod):
     model = build_model(cfg)
     opt = AdamWConfig(state_dtype=dist.get("opt_state_dtype", "fp32"),
                       master_fp32=dist.get("master_fp32", False))
-    with mesh:
+    with jax.set_mesh(mesh):
         pa = model.abstract_params(mesh, rules)
         batch = input_specs(cfg, shape, mesh, rules)
         if shape.kind == "train":
@@ -68,6 +66,7 @@ def compile_cell(arch, shape_name, multi_pod):
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     arch, shape_name, mesh_kind = sys.argv[1], sys.argv[2], sys.argv[3]
     top = int(sys.argv[4]) if len(sys.argv) > 4 else 20
     c, mesh = compile_cell(arch, shape_name, mesh_kind == "multi")

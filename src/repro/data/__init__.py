@@ -1,5 +1,5 @@
 from .synthetic import (DAG_SCHEMA_VERSION, SyntheticDAG, SyntheticLMData,
-                        synthetic_dag)
+                        scale_estimator, synthetic_dag, synthetic_samples)
 
 __all__ = ["DAG_SCHEMA_VERSION", "SyntheticDAG", "SyntheticLMData",
-           "synthetic_dag"]
+           "scale_estimator", "synthetic_dag", "synthetic_samples"]

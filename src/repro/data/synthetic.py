@@ -14,6 +14,10 @@ The "dataset downsampling" used by Lotaru's local phase is just a smaller
 layered workflow generator (seeded; width/depth/fan-out/data-size
 distributions) that scales past 10k tasks — the stress harness for
 data-aware HEFT and the sample source for the hypothesis oracle suite.
+
+``scale_estimator`` builds a fitted ``LotaruEstimator`` at any (T, N)
+from seeded samples and benches: the estimator behind the fused tick's
+scale sweep and its on-chip smoke run.
 """
 from __future__ import annotations
 
@@ -23,6 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import blr
+from repro.core.estimator import FittedTask, LotaruEstimator
+from repro.core.profiler import BenchResult
 from repro.models import ModelConfig
 
 
@@ -239,3 +246,64 @@ def synthetic_dag(width: int = 8, depth: int = 10, fanout: float = 2.0,
               "data_gb_mean": data_gb_mean, "data_gb_sigma": data_gb_sigma,
               "work_mean": work_mean, "work_sigma": work_sigma, "seed": seed}
     return SyntheticDAG(succ, pred, data_gb, work, params=params)
+
+
+# ---------------------------------------------------------------------------
+# Fitted estimators at arbitrary (T, N) (tick scale sweep + chip smoke)
+# ---------------------------------------------------------------------------
+def synthetic_samples(n_tasks: int, n_samples: int = 8, seed: int = 0):
+    """Per-task local (sizes, runtimes) samples: 70% of tasks scale
+    linearly with input size, the rest are flat with noise (the
+    uncorrelated median fallback)."""
+    rng = np.random.default_rng(seed)
+    sizes_list, runtimes_list = [], []
+    for i in range(n_tasks):
+        sizes = np.geomspace(1.0, 256.0, n_samples) * rng.uniform(0.5, 2.0)
+        if rng.random() < 0.7:
+            rts = (rng.uniform(0.1, 5.0) * sizes + rng.uniform(1, 50)
+                   + rng.normal(0, 0.05, n_samples))
+        else:
+            rts = rng.uniform(20, 200) + rng.normal(0, 0.5, n_samples)
+        sizes_list.append(sizes)
+        runtimes_list.append(np.abs(rts))
+    return sizes_list, runtimes_list
+
+
+def _synthetic_bench(name: str, rng) -> BenchResult:
+    """Microbenchmark results of a node drawn from ``rng``."""
+    return BenchResult(node=name,
+                       cpu_events_s=float(rng.uniform(300.0, 900.0)),
+                       matmul_gflops=float(rng.uniform(50.0, 200.0)),
+                       mem_gbps=float(rng.uniform(10.0, 40.0)),
+                       io_read_mbps=float(rng.uniform(200.0, 800.0)),
+                       io_write_mbps=float(rng.uniform(200.0, 800.0)),
+                       link_gbps=0.0)
+
+
+def scale_estimator(n_tasks: int, n_nodes: int, seed: int = 0):
+    """A real ``LotaruEstimator`` at arbitrary (T, N): synthetic benches
+    for N nodes, one ``fit_task_batch`` solve for T tasks injected as
+    ``FittedTask``s (the batch cache is primed with the same fit, exactly
+    like ``fit_tasks``) — the paper's five workflows top out at T=14, so
+    the sweep needs shapes the workflow registry cannot provide.
+
+    Returns ``(estimator, task_names, node_names)``.
+    """
+    rng = np.random.default_rng(seed)
+    local = _synthetic_bench("local-cpu", rng)
+    nodes = [f"n{j}" for j in range(n_nodes)]
+    benches = {n: _synthetic_bench(n, rng) for n in nodes}
+    est = LotaruEstimator(local, benches, bias_correction=True,
+                          bias_empirical_bayes=True)
+    sizes_list, runtimes_list = synthetic_samples(n_tasks, seed=seed)
+    batch = blr.fit_task_batch(sizes_list, runtimes_list)
+    names = [f"t{i}" for i in range(n_tasks)]
+    ws = rng.uniform(0.2, 0.95, n_tasks)
+    for i, (name, model) in enumerate(zip(names,
+                                          blr.unstack_task_models(batch))):
+        est.tasks[name] = FittedTask(model=model, w=float(ws[i]),
+                                     sizes=np.asarray(sizes_list[i]),
+                                     runtimes=np.asarray(runtimes_list[i]))
+    est._batch_cache = (names, [est.tasks[n] for n in names], batch,
+                        np.asarray(ws, np.float64))
+    return est, names, nodes

@@ -82,7 +82,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, block_q: int = 128,
                     block_k: int = 128, kv_len: int | None = None,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D); Hq % Hkv == 0.
 
     Returns (B, Hq, Sq, D) in q.dtype.  ``kv_len`` masks a padded KV tail

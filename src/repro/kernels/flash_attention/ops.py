@@ -9,7 +9,7 @@ from .ref import attention_ref
 
 
 def mha(q, k, v, *, causal: bool = True, kv_len=None, mode: str = "pallas",
-        interpret: bool = True, block_q: int = 128, block_k: int = 128):
+        interpret: bool, block_q: int = 128, block_k: int = 128):
     """Layout (B, S, H, D) — the model-stack convention.
 
     mode="pallas": blocked kernel (interpret=True on CPU, False on TPU);

@@ -78,7 +78,7 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, state_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, a, B_, C_, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, dt, a, B_, C_, *, chunk: int = 128, interpret: bool):
     """x: (B, T, H, P); dt: (B, T, H) (post-softplus); a: (H,) negative;
     B_, C_: (B, T, G, N).  Returns y: (B, T, H, P) fp32.
 

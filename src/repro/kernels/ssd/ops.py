@@ -6,7 +6,7 @@ from .ref import ssd_ref
 
 
 def ssd(x, dt, a, B_, C_, *, chunk: int = 128, mode: str = "pallas",
-        interpret: bool = True):
+        interpret: bool):
     if mode == "pallas":
         return ssd_scan(x, dt, a, B_, C_, chunk=chunk, interpret=interpret)
     return ssd_ref(x, dt, a, B_, C_)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: for each cell
@@ -15,6 +12,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -128,7 +126,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     chips = mesh.size
     params_abs = model.abstract_params(mesh, rules)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_abs = tree_defs_to_abstract(state_defs(model.param_defs, opt_cfg),
                                             mesh, rules)
@@ -212,6 +210,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> None:
+    # 512 host devices stand in for the chips; set before the backend
+    # starts, which nothing before this point does
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

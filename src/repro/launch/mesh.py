@@ -8,8 +8,17 @@ is 16x16 = 256 chips ("data", "model"); the multi-pod mesh adds a leading
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.models.common import AxisRules, mesh_axis_sizes
+
+
+def _auto(axes) -> tuple:
+    """Auto axis types for every axis: the models and the fleet place
+    their arrays with ``NamedSharding`` and ``with_sharding_constraint``
+    and let the partitioner propagate the rest, which Explicit axes
+    (``jax.make_mesh``'s default) refuse inside ``vmap`` and gathers."""
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,12 +30,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     devices = jax.devices()
     if len(devices) > need:   # e.g. single-pod mesh under a 512-device dry-run
         devices = devices[:need]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=_auto(axes))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh (tests use small ones, elastic re-meshing uses this)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_fleet_mesh(*, wf: int | None = None, task: int = 1):
@@ -41,7 +51,8 @@ def make_fleet_mesh(*, wf: int | None = None, task: int = 1):
         raise ValueError(f"{n} devices not divisible by task={task}")
     if wf is None:
         wf = n // task
-    return jax.make_mesh((wf, task), ("wf", "task"))
+    return jax.make_mesh((wf, task), ("wf", "task"),
+                         axis_types=_auto(("wf", "task")))
 
 
 def make_rules(mesh, *, fsdp_over_pod: bool = False,

@@ -49,16 +49,22 @@ class ServeLoop:
         self.straggler_steps = 0
         self.step_times: list[float] = []
 
-    def run_batch(self, requests: list[Request]) -> list[Request]:
-        assert len(requests) <= self.max_batch
+    def pack(self, requests: list[Request]):
+        """Left-pad the prompts into one (B, T) token batch and allocate
+        caches for the longest generation.  Returns ``(batch, caches)``."""
         B = len(requests)
         T = max(len(r.prompt) for r in requests)
         toks = np.zeros((B, T), np.int32)
         for i, r in enumerate(requests):
             toks[i, T - len(r.prompt):] = r.prompt      # left-pad
-        batch = {"tokens": jnp.asarray(toks)}
         caches = self.model.init_caches(B, max_len=T + max(
             r.max_new for r in requests), cross_len=T)
+        return {"tokens": jnp.asarray(toks)}, caches
+
+    def run_batch(self, requests: list[Request]) -> list[Request]:
+        assert len(requests) <= self.max_batch
+        batch, caches = self.pack(requests)
+        T = batch["tokens"].shape[1]
         logits, caches = self.prefill(self.params, batch, caches)
         tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
         n_steps = max(r.max_new for r in requests)
@@ -79,6 +85,22 @@ class ServeLoop:
                     r.out.append(int(tok[i]))
         return requests
 
+    def serve(self, requests: list[Request]) -> list[Request]:
+        """Answer a queue of requests, ``max_batch`` at a time."""
+        done = []
+        for i in range(0, len(requests), self.max_batch):
+            done.extend(self.run_batch(requests[i:i + self.max_batch]))
+        return done
+
+
+def make_requests(cfg, n: int, max_new: int, seed: int = 0) -> list[Request]:
+    """``n`` requests with random 4-16 token prompts."""
+    rng = np.random.default_rng(seed)
+    return [Request(rid=i,
+                    prompt=rng.integers(0, cfg.vocab, rng.integers(4, 17)),
+                    max_new=max_new)
+            for i in range(n)]
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -91,16 +113,9 @@ def main():
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     loop = ServeLoop(cfg)
-    rng = np.random.default_rng(0)
-    queue = [Request(rid=i,
-                     prompt=rng.integers(0, cfg.vocab, rng.integers(4, 17)),
-                     max_new=args.max_new)
-             for i in range(args.requests)]
+    queue = make_requests(cfg, args.requests, args.max_new)
     t0 = time.time()
-    done = []
-    while queue:
-        batch, queue = queue[:loop.max_batch], queue[loop.max_batch:]
-        done.extend(loop.run_batch(batch))
+    done = loop.serve(queue)
     dt = time.time() - t0
     toks = sum(len(r.out) for r in done)
     print(f"served {len(done)} requests, {toks} tokens in {dt:.1f}s "

@@ -20,12 +20,11 @@ def _constrain_like_params(grads, model: Model, rules: AxisRules):
     """Pin gradient shardings to the parameter shardings.  Without this,
     sharding propagation through the rematted backward can replicate large
     gradient leaves (measured +5x temp HBM on the MoE cells)."""
-    specs = tree_defs_to_specs(model.param_defs, rules)
-    try:
-        return jax.tree.map(
-            lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, specs)
-    except (ValueError, RuntimeError):
+    if jax.sharding.get_abstract_mesh().empty:
         return grads
+    specs = tree_defs_to_specs(model.param_defs, rules)
+    return jax.tree.map(
+        lambda g, s: jax.lax.with_sharding_constraint(g, s), grads, specs)
 
 
 def make_train_step(model: Model, rules: AxisRules, opt_cfg: AdamWConfig,

@@ -312,9 +312,10 @@ def tree_defs_init(defs, key):
 
 
 def logical_constraint(x, rules: AxisRules, *logical: str | None):
-    """sharding constraint by logical axes; no-op outside a mesh context."""
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, rules.resolve(*logical, dims=x.shape))
-    except (ValueError, RuntimeError):
+    """Sharding constraint by logical axes under the mesh that
+    ``jax.set_mesh`` made current; a no-op when no mesh is set (single
+    device).  A spec the mesh cannot take raises."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(
+        x, rules.resolve(*logical, dims=x.shape))

@@ -38,7 +38,7 @@ SCRIPT = textwrap.dedent("""
         cfg = smoke_config(arch).with_(moe_groups=4)
         model = build_model(cfg)
         opt = AdamWConfig()
-        with mesh:
+        with jax.set_mesh(mesh):
             pa = model.abstract_params(mesh, rules)
             oa = tree_defs_to_abstract(state_defs(model.param_defs, opt), mesh, rules)
             batch = input_specs(cfg, ShapeSpec("t", "train", 64, 8), mesh, rules)
