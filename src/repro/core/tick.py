@@ -269,18 +269,25 @@ class TickEngine:
             self._touched.add(i)
         if self._rel_dirty:
             self._sync_reliability()
-        with self.tracer.span("tick_step", n=len(obs)):
+        tr = self.tracer
+        with tr.span("tick_step", n=len(obs)):
             state, mean, std, _y = tick_step(self.state,
                                              jnp.asarray(packed, dt),
                                              self.size, host_deadjust=True)
-            self._mean = np.asarray(mean, np.float64)
-            self._std = np.asarray(std, np.float64)
+            # the estimates' copies to the host wait for the device
+            with tr.span("tick_fetch", n=len(obs)):
+                self._mean = np.asarray(mean, np.float64)
+                self._std = np.asarray(std, np.float64)
         self.state = state
         self._model = _attach_log(state.model, self._log)
         if self._bias is not None:
-            self._bias.counts = np.asarray(state.bias_counts, np.float64)
-            self._bias.log_sum = np.asarray(state.bias_log_sum, np.float64)
-            self._bias.log_sq = np.asarray(state.bias_log_sq, np.float64)
+            with tr.span("bias_fetch", n=len(obs)):
+                self._bias.counts = np.asarray(state.bias_counts,
+                                               np.float64)
+                self._bias.log_sum = np.asarray(state.bias_log_sum,
+                                                np.float64)
+                self._bias.log_sq = np.asarray(state.bias_log_sq,
+                                               np.float64)
             self._bias._sigma_r_cache = None
         return [float(v) for v in ys]
 

@@ -15,13 +15,18 @@ from __future__ import annotations
 import numpy as np
 
 
+def _data(e) -> dict:
+    """The payload of an ``Event``, or a JSON event dict as it is."""
+    return e.data if hasattr(e, "data") else e
+
+
 def _span_payloads(events, phase: str | None = None) -> list[dict]:
     out = []
     for e in events:
         kind = e.kind if hasattr(e, "kind") else e.get("kind")
         if kind != "span":
             continue
-        d = dict(e.data) if hasattr(e, "data") else dict(e)
+        d = dict(_data(e))
         if phase is None or d.get("phase") == phase:
             if hasattr(e, "t_sim"):
                 d.setdefault("t_sim", e.t_sim)
@@ -63,24 +68,30 @@ def phase_breakdown(events) -> dict[str, dict]:
 
 
 def slowest_spans(events, n: int = 5) -> list[dict]:
-    """The ``n`` slowest spans of the trace (phase, dur_s, t_sim, extra
-    payload), slowest first — the "which tick hurt" view."""
+    """The ``n`` slowest spans of the trace (phase, dur_s, cpu_s where
+    recorded, t_sim, extra payload), slowest first — the "which tick
+    hurt" view; a ``cpu_s`` far below ``dur_s`` says it waited."""
     spans = _span_payloads(events)
     spans.sort(key=lambda d: -float(d.get("dur_s", 0.0)))
     return spans[:n]
 
 
 def tick_latency_summary(events) -> dict:
-    """One roll-up for benchmarks: the per-phase breakdown plus the
-    total traced wall time, the compile share, and the steady-state
-    per-tick cost (sum of every phase's steady mean — the cost of one
-    fully-instrumented observe → re-predict → re-plan tick once all
-    executables are compiled)."""
+    """One roll-up for benchmarks: the per-phase breakdown (every phase,
+    nested ones included) plus the total traced wall time, the compile
+    share, and the steady-state per-tick cost (sum of every phase's
+    steady mean — the cost of one fully-instrumented observe →
+    re-predict → re-plan tick once all executables are compiled).  The
+    totals sum top-level spans only (``parent`` None, or absent in a
+    trace that predates it), so a nested span's time counts once."""
+    events = list(events)
     phases = phase_breakdown(events)
-    total = sum(p["total_s"] for p in phases.values())
-    first = sum(p["first_s"] for p in phases.values())
-    steady_tick = sum(p["steady_mean_s"] for p in phases.values()
-                     if np.isfinite(p["steady_mean_s"]))
+    top = phase_breakdown(e for e in events
+                          if _data(e).get("parent") is None)
+    total = sum(p["total_s"] for p in top.values())
+    first = sum(p["first_s"] for p in top.values())
+    steady_tick = sum(p["steady_mean_s"] for p in top.values()
+                      if np.isfinite(p["steady_mean_s"]))
     return {
         "phases": phases,
         "traced_total_s": total,

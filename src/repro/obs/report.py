@@ -151,13 +151,17 @@ def render_report(events, min_obs: int = 20) -> str:
             f"~{_fmt_s(summ['steady_tick_s'])}")
         slow = slowest_spans(events, 5)
         if slow:
-            lines.append("  slowest spans:")
+            lines.append("  slowest spans (wall, cpu):")
             for s in slow:
                 extra = {k: v for k, v in s.items()
-                         if k not in ("phase", "dur_s", "t_sim", "t_wall")}
+                         if k not in ("phase", "dur_s", "cpu_s", "t_sim",
+                                      "t_wall")
+                         and not (k == "parent" and v is None)}
                 extra_s = f"  {extra}" if extra else ""
                 lines.append(
-                    f"    {_fmt_s(s['dur_s']):>9s}  {s['phase']:<16s}"
+                    f"    {_fmt_s(s['dur_s']):>9s} "
+                    f"{_fmt_s(s.get('cpu_s', math.nan)):>9s}  "
+                    f"{s['phase']:<16s}"
                     f"t_sim={_fmt(s.get('t_sim', 0.0), prec=5)}{extra_s}")
     lines.append("")
 

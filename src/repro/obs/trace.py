@@ -141,6 +141,7 @@ class EventLog:
     def __init__(self):
         self.events: list[Event] = []
         self._t0 = time.perf_counter()
+        self._open: list[str] = []      # phases of the spans now open
 
     # ---- Tracer protocol ---------------------------------------------------
     def emit(self, kind: str, t_sim: float = 0.0, **data) -> None:
@@ -155,16 +156,23 @@ class EventLog:
     @contextmanager
     def span(self, phase: str, t_sim: float = 0.0, **data):
         """Time a region: on exit one ``span`` event is emitted carrying
-        ``phase``, the wall duration ``dur_s``, and any extra payload."""
-        w0 = time.perf_counter()
+        ``phase``, the wall duration ``dur_s``, the calling thread's CPU
+        time over it ``cpu_s`` (far below ``dur_s``: the region waited
+        rather than computed), the ``parent`` phase of the span open
+        around it (None at top level), and any extra payload."""
+        parent = self._open[-1] if self._open else None
+        self._open.append(phase)
+        w0, c0 = time.perf_counter(), time.thread_time()
         try:
             yield self
         finally:
+            dur, cpu = time.perf_counter() - w0, time.thread_time() - c0
+            self._open.pop()
             self.events.append(Event(
                 kind="span", t_sim=float(t_sim),
                 t_wall=w0 - self._t0,
-                data={"phase": phase,
-                      "dur_s": time.perf_counter() - w0, **data}))
+                data={"phase": phase, "dur_s": dur, "cpu_s": cpu,
+                      "parent": parent, **data}))
 
     # ---- queries -----------------------------------------------------------
     def __len__(self) -> int:

@@ -958,31 +958,32 @@ class OnlineExecutor:
                 batch = []
                 gates = []
                 pit_of = getattr(self._api, "predict_pit_node", None)
-                for ctid, cnode, _ in completions:
-                    run = trace.records[rec_idx[ctid]]
-                    name = self.task_name[ctid]
-                    ntype = self.grid.type_of(cnode).name
-                    lo, hi = self._api.predict_interval_node(
-                        name, ntype, self.size, self.confidence)
-                    gate = not (lo <= run.runtime <= hi)
-                    gates.append(gate)
-                    batch.append((name, ntype, self.size, run.runtime))
-                    if tr.enabled:
-                        # the tick-start belief, read-only: the same
-                        # interval the surprise gate consumed, plus the
-                        # PIT of the realised runtime under it
-                        pit = (pit_of(name, ntype, self.size, run.runtime)
-                               if pit_of is not None else None)
-                        tr.emit("observe", t_sim=t, task=ctid, name=name,
-                                node=run.node, node_type=ntype,
-                                runtime=run.runtime,
-                                pred_mean=run.pred_mean,
-                                pred_std=run.pred_std,
-                                lo=lo, hi=hi, covered=not gate, pit=pit)
-                        if gate:
-                            tr.emit("surprise", t_sim=t, task=ctid,
-                                    name=name, node_type=ntype,
-                                    runtime=run.runtime, lo=lo, hi=hi)
+                with tr.span("surprise_gate", t_sim=t, n=len(completions)):
+                    for ctid, cnode, _ in completions:
+                        run = trace.records[rec_idx[ctid]]
+                        name = self.task_name[ctid]
+                        ntype = self.grid.type_of(cnode).name
+                        lo, hi = self._api.predict_interval_node(
+                            name, ntype, self.size, self.confidence)
+                        gate = not (lo <= run.runtime <= hi)
+                        gates.append(gate)
+                        batch.append((name, ntype, self.size, run.runtime))
+                        if tr.enabled:
+                            # the tick-start belief, read-only: the same
+                            # interval the surprise gate consumed, plus the
+                            # PIT of the realised runtime under it
+                            pit = (pit_of(name, ntype, self.size, run.runtime)
+                                   if pit_of is not None else None)
+                            tr.emit("observe", t_sim=t, task=ctid, name=name,
+                                    node=run.node, node_type=ntype,
+                                    runtime=run.runtime,
+                                    pred_mean=run.pred_mean,
+                                    pred_std=run.pred_std,
+                                    lo=lo, hi=hi, covered=not gate, pit=pit)
+                            if gate:
+                                tr.emit("surprise", t_sim=t, task=ctid,
+                                        name=name, node_type=ntype,
+                                        runtime=run.runtime, lo=lo, hi=hi)
                 local_rts = self._api.observe_batch(batch)
                 for (name, ntype, _, runtime), local_rt in zip(batch,
                                                                local_rts):

@@ -11,7 +11,7 @@ from repro.obs import (EVENT_KINDS, Event, EventLog, MetricsRegistry,
                        NULL_TRACER, RunningMedian, calibration_summary,
                        chrome_trace_events, load_jsonl, phase_breakdown,
                        pit_uniformity, render_report, report_dict,
-                       running_median, tick_latency_summary)
+                       running_median, slowest_spans, tick_latency_summary)
 from repro.online.buffer import ObservationBuffer
 from repro.online.executor import ExecutionTrace, TaskRun
 from repro.sched.simulator import FaultInjector
@@ -30,21 +30,24 @@ def _faulty(tracer=None, **kw):
 # ---------------------------------------------------------------------------
 # tracing is read-only: attaching a tracer never perturbs the loop
 # ---------------------------------------------------------------------------
-def test_tracer_disabled_is_bit_exact():
+@pytest.mark.parametrize("fused", [False, True])
+def test_tracer_disabled_is_bit_exact(fused):
     """The PR 5 contract, extended: the executor's full output — every
     counter, record, censored run and observation, via ``to_dict`` — is
     bit-identical whether no tracer, the NULL_TRACER, or a collecting
-    ``EventLog`` is attached.  Tracing observes; it never steers."""
-    base = _faulty(tracer=None).run().to_dict()
+    ``EventLog`` is attached, on the legacy and the fused tick alike.
+    Tracing observes; it never steers."""
+    base = _faulty(tracer=None, fused=fused).run().to_dict()
     for tracer in (NULL_TRACER, EventLog()):
-        got = _faulty(tracer=tracer).run().to_dict()
+        got = _faulty(tracer=tracer, fused=fused).run().to_dict()
         assert json.dumps(got, sort_keys=True) == \
             json.dumps(base, sort_keys=True)
 
 
-def test_tracer_bit_exact_fault_free():
-    a = _scenario(online=True, tracer=None).run().to_dict()
-    b = _scenario(online=True, tracer=EventLog()).run().to_dict()
+@pytest.mark.parametrize("fused", [False, True])
+def test_tracer_bit_exact_fault_free(fused):
+    a = _scenario(online=True, tracer=None, fused=fused).run().to_dict()
+    b = _scenario(online=True, tracer=EventLog(), fused=fused).run().to_dict()
     assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
 
 
@@ -79,6 +82,56 @@ def test_traced_run_emits_lifecycle_events():
     # sim clock on events is monotone within the heap's pop order
     ticks = [e.t_sim for e in log.filter("tick")]
     assert all(a <= b + 1e-9 for a, b in zip(ticks, ticks[1:]))
+
+
+def test_fused_run_spans_inside_the_tick():
+    """A traced fused run splits each completion tick into its surprise
+    gate, the engine's tick (with the estimates' fetch nested in it) and
+    the bias statistics' fetch; every span records its parent and the
+    thread's CPU time over it."""
+    log = EventLog()
+    _faulty(tracer=log, fused=True).run()
+    ticks = len(log.filter("predict"))          # one per completion tick
+    assert ticks > 0
+    for phase in ("surprise_gate", "tick_step", "tick_fetch", "bias_fetch"):
+        assert len(log.spans(phase)) == ticks, phase
+    parents = {e.data["phase"]: set() for e in log.spans()}
+    for e in log.spans():
+        parents[e.data["phase"]].add(e.data["parent"])
+        assert 0.0 <= e.data["cpu_s"]
+    assert parents.pop("tick_fetch") == {"tick_step"}
+    assert all(p == {None} for p in parents.values()), parents
+    # the nested fetch lies inside its tick_step on the wall clock
+    steps = log.spans("tick_step")
+    for f, st in zip(log.spans("tick_fetch"), steps):
+        assert st.t_wall <= f.t_wall
+        assert f.t_wall + f.data["dur_s"] <= \
+            st.t_wall + st.data["dur_s"] + 1e-9
+    gates = log.spans("surprise_gate")
+    assert sum(e.data["n"] for e in gates) == len(log.filter("observe"))
+
+
+def test_span_parent_stack_and_cpu_time():
+    log = EventLog()
+    with log.span("outer"):
+        with log.span("inner", n=2):
+            sum(range(20000))
+        with log.span("inner"):
+            pass
+    with log.span("after"):
+        pass
+    got = [(e.data["phase"], e.data["parent"]) for e in log.spans()]
+    assert got == [("inner", "outer"), ("inner", "outer"),
+                   ("outer", None), ("after", None)]
+    assert log.spans("inner")[0].data["n"] == 2
+    for e in log.spans():
+        assert 0.0 <= e.data["cpu_s"]
+    # a span that waits records wall time but (almost) no CPU time
+    import time
+    with log.span("sleep"):
+        time.sleep(0.05)
+    slept = log.spans("sleep")[0].data
+    assert slept["dur_s"] >= 0.05 and slept["cpu_s"] < 0.5 * slept["dur_s"]
 
 
 def test_unknown_event_kind_warns_not_raises():
@@ -277,6 +330,45 @@ def test_phase_breakdown_splits_compile():
     s = tick_latency_summary(events)
     assert s["compile_total_s"] == pytest.approx(1.05)
     assert s["traced_total_s"] == pytest.approx(1.45)
+
+
+def test_nested_spans_counted_once():
+    """``tick_latency_summary`` sums top-level spans only, so a nested
+    span's time is not counted twice; ``phase_breakdown`` keeps every
+    phase; ``slowest_spans`` and the report carry ``cpu_s`` beside
+    ``dur_s``.  Events without ``parent`` (older traces) are top level."""
+    def span(phase, dur, cpu, parent, t_wall):
+        return Event(kind="span", t_sim=0.0, t_wall=t_wall,
+                     data={"phase": phase, "dur_s": dur, "cpu_s": cpu,
+                           "parent": parent})
+    events = [span("gate", 0.4, 0.35, None, 0.0),
+              span("fetch", 0.25, 0.01, "step", 1.0),
+              span("step", 1.0, 0.2, None, 0.9),
+              span("gate", 0.3, 0.29, None, 3.0),
+              span("fetch", 0.125, 0.01, "step", 4.0),
+              span("step", 0.5, 0.1, None, 3.9),
+              _span("plan", 0.25, 5.0)]
+    pb = phase_breakdown(events)
+    assert set(pb) == {"gate", "fetch", "step", "plan"}
+    assert pb["fetch"]["count"] == 2
+    assert pb["fetch"]["total_s"] == pytest.approx(0.375)
+    s = tick_latency_summary(events)
+    assert set(s["phases"]) == set(pb)
+    assert s["traced_total_s"] == pytest.approx(0.7 + 1.5 + 0.25)
+    assert s["compile_total_s"] == pytest.approx(0.4 + 1.0 + 0.25)
+    assert s["steady_tick_s"] == pytest.approx(0.3 + 0.5)
+    # the same from a JSONL round trip (plain dicts)
+    dicts = [e.to_json() for e in events]
+    assert tick_latency_summary(dicts)["traced_total_s"] == \
+        pytest.approx(s["traced_total_s"])
+    slow = slowest_spans(events, 2)
+    assert [(d["phase"], d["dur_s"], d["cpu_s"]) for d in slow] == \
+        [("step", 1.0, 0.2), ("step", 0.5, 0.1)]
+    text = render_report(events)
+    assert "slowest spans (wall, cpu)" in text
+    row = next(ln for ln in text.splitlines() if "fetch" in ln
+               and "t_sim=" in ln)
+    assert "250.00ms" in row and "10.00ms" in row and "'step'" in row
 
 
 def test_profiling_on_real_trace():

@@ -109,6 +109,35 @@ def test_tick_engine_matches_legacy_observe_batch(cluster):
             np.testing.assert_allclose(pb, pa, rtol=TOL, atol=TOL)
 
 
+def test_tick_engine_spans(cluster):
+    """Each tick enters ``tick_step`` with the estimates' fetch nested in
+    it, then fetches the bias statistics outside it; both carry the
+    batch size.  Tracing leaves the engine's numbers as they were."""
+    from repro.obs import EventLog
+    wf, size = "eager", INPUTS[("eager", 1)]
+    est_a, by_name = _fitted(cluster, wf, size)
+    est_b, _ = _fitted(cluster, wf, size)
+    nodes = [nt.name for nt in target_nodes()]
+    log = EventLog()
+    plain = TickEngine(est_a, nodes, size=size)
+    traced = TickEngine(est_b, nodes, size=size, tracer=log)
+    names = list(by_name)
+    batches = [[(names[0], nodes[0], size, 30.0)],
+               [(names[1], nodes[1], size, 12.0),
+                (names[2], nodes[2], size, 55.0)]]
+    for batch in batches:
+        assert traced.observe_batch(batch) == plain.observe_batch(batch)
+        np.testing.assert_array_equal(traced.predict_matrix(nodes, size),
+                                      plain.predict_matrix(nodes, size))
+    got = [(e.data["phase"], e.data["parent"], e.data["n"])
+           for e in log.spans()]
+    assert got == [("tick_fetch", "tick_step", 1), ("tick_step", None, 1),
+                   ("bias_fetch", None, 1),
+                   ("tick_fetch", "tick_step", 2), ("tick_step", None, 2),
+                   ("bias_fetch", None, 2)]
+    assert all(e.data["cpu_s"] >= 0.0 for e in log.spans())
+
+
 def test_tick_step_donates_and_predict_state_matches(cluster):
     wf, size = "bacass", INPUTS[("bacass", 1)]
     est, _ = _fitted(cluster, wf, size)
