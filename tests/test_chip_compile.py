@@ -77,6 +77,18 @@ def test_tick_step_full_sweep_ceiling(one_chip):
     assert 0 < _hbm(compiled) < HBM_BYTES
 
 
+def test_gate_table_full_sweep_ceiling(one_chip):
+    """The surprise gate's table of the same model, float32."""
+    from repro.core.tick import gate_table
+    est, _names, nodes = scale_estimator(4096, 256)
+    state, _ = build_state(est, nodes)
+    size = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip,
+                                weak_type=True)
+    compiled = gate_table.lower(_abstract(state.model, one_chip),
+                                size).compile()
+    assert 0 < _hbm(compiled) < HBM_BYTES
+
+
 def test_fleet_tick_step_four_chips(topo):
     """The W = 64, (128, 16) fleet tick sharded over a (4, 1) mesh: the
     estimate matrices come out split over all four chips."""

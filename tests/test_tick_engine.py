@@ -138,6 +138,141 @@ def test_tick_engine_spans(cluster):
     assert all(e.data["cpu_s"] >= 0.0 for e in log.spans())
 
 
+@pytest.fixture
+def _f32():
+    """The device dtype of a TPU run: x64 off for one test, back on after."""
+    jax.config.update("jax_enable_x64", False)
+    jax.clear_caches()
+    yield
+    jax.config.update("jax_enable_x64", True)
+    jax.clear_caches()
+
+
+#: engine vs legacy in float32: the same formulas in another program
+#: order, so a few float32 ulps apart (about 4e-6 measured on the CPU)
+F32_TOL = 5e-5
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+@pytest.mark.parametrize("confidence", [0.5, 0.9, 0.99])
+def test_tick_engine_gate_matches_legacy(cluster, request, confidence,
+                                         dtype):
+    """The surprise gate's interval and the PIT, which the engine answers
+    on the host from the gate table its tick copied, match the legacy
+    estimator's on every (task, node) pair: Student-t rows and the
+    median fallback, bias pairs observed and not.  To ``TOL`` under x64,
+    and in float32, the dtype the chip runs, to ``F32_TOL``."""
+    tol = TOL
+    if dtype == "float32":
+        request.getfixturevalue("_f32")
+        tol = F32_TOL
+    wf, size = "eager", INPUTS[("eager", 1)]
+    est_a, by_name = _fitted(cluster, wf, size)
+    est_b, _ = _fitted(cluster, wf, size)
+    names = list(by_name)
+    assert est_a.tasks[names[0]].model.post.mu.dtype == np.dtype(dtype)
+    nodes = [nt.name for nt in target_nodes()]
+    engine = TickEngine(est_b, nodes, size=size)
+    assert not all(est_a.tasks[n].model.correlated for n in names)
+    batches = [[(names[0], nodes[0], size, 30.0),
+                (names[-1], nodes[1], size, 12.0)],
+               [(names[-1], nodes[1], size, 16.0),
+                (names[4], nodes[2], size, 55.0)]]
+    for tick in range(len(batches) + 1):
+        for name in names:
+            for nt in nodes:
+                lo_a, hi_a = est_a.predict_interval_node(name, nt, size,
+                                                         confidence)
+                lo_b, hi_b = engine.predict_interval_node(name, nt, size,
+                                                          confidence)
+                assert lo_b == pytest.approx(lo_a, rel=tol, abs=tol)
+                assert hi_b == pytest.approx(hi_a, rel=tol, abs=tol)
+                for rt in (0.5 * hi_a, hi_a):
+                    assert engine.predict_pit_node(
+                        name, nt, size, rt) == pytest.approx(
+                        est_a.predict_pit_node(name, nt, size, rt),
+                        rel=tol, abs=tol)
+        if tick < len(batches):
+            est_a.observe_batch(batches[tick])
+            engine.observe_batch(batches[tick])
+    assert est_a.bias_point(names[-1], nodes[1]) != 1.0
+
+
+def test_tick_engine_gate_refuses_other_size(cluster):
+    """The engine answers the gate at its own size only, as
+    ``predict_matrix`` does."""
+    wf, size = "bacass", INPUTS[("bacass", 1)]
+    est, by_name = _fitted(cluster, wf, size)
+    nodes = [nt.name for nt in target_nodes()]
+    engine = TickEngine(est, nodes, size=size)
+    name = list(by_name)[0]
+    with pytest.raises(ValueError, match="its own size"):
+        engine.predict_interval_node(name, nodes[0], 0.5 * size)
+    with pytest.raises(ValueError, match="its own size"):
+        engine.predict_pit_node(name, nodes[0], 0.5 * size, 30.0)
+    assert engine.predict_interval_node(name, nodes[0], size)[1] > 0.0
+
+
+def test_gate_dispatches_nothing_after_a_tick(cluster, monkeypatch):
+    """After a tick the engine answers the surprise gate and the PIT at
+    its size from host copies: with the state's device buffers deleted,
+    implicit transfers disallowed and eager primitives counted, every
+    pair still gets an answer and no primitive runs."""
+    from jax._src import dispatch
+    wf, size = "eager", INPUTS[("eager", 1)]
+    est, by_name = _fitted(cluster, wf, size)
+    nodes = [nt.name for nt in target_nodes()]
+    engine = TickEngine(est, nodes, size=size)
+    names = list(by_name)
+    engine.observe_batch([(names[0], nodes[0], size, 30.0),
+                          (names[-1], nodes[1], size, 12.0)])
+    for leaf in jax.tree.leaves(engine.state):
+        leaf.delete()
+    ran = []
+    real = dispatch.apply_primitive
+
+    def counted(prim, *args, **kw):
+        ran.append(prim)
+        return real(prim, *args, **kw)
+
+    monkeypatch.setattr(dispatch, "apply_primitive", counted)
+    with jax.transfer_guard("disallow"):
+        for name in names:
+            for nt in nodes:
+                lo, hi = engine.predict_interval_node(name, nt, size, 0.9)
+                assert 0.0 <= lo <= hi
+                pit = engine.predict_pit_node(name, nt, size, 30.0)
+                assert 0.0 <= pit <= 1.0
+    assert ran == []
+
+
+def test_engine_program_names(cluster, monkeypatch):
+    """The benchmark's device-time readers find the tick's program by the
+    fragment ``tick_core`` of its name: the tick the engine runs has it,
+    and the gate table's program, run at build and after each tick, has
+    not."""
+    import re
+    from repro.core import tick
+    wf, size = "bacass", INPUTS[("bacass", 1)]
+    est, by_name = _fitted(cluster, wf, size)
+    nodes = [nt.name for nt in target_nodes()]
+    ran = []
+    for attr in ("tick_step", "gate_table"):
+        jitted = getattr(tick, attr)
+
+        def spy(*args, _jitted=jitted, _attr=attr, **kw):
+            text = _jitted.lower(*args, **kw).as_text()
+            ran.append((_attr, re.search(r"module @(\S+)", text).group(1)))
+            return _jitted(*args, **kw)
+
+        monkeypatch.setattr(tick, attr, spy)
+    engine = TickEngine(est, nodes, size=size)
+    engine.observe_batch([(list(by_name)[0], nodes[0], size, 30.0)])
+    assert [a for a, _ in ran] == ["gate_table", "tick_step", "gate_table"]
+    for attr, program in ran:
+        assert ("tick_core" in program) == (attr == "tick_step"), program
+
+
 def test_tick_step_donates_and_predict_state_matches(cluster):
     wf, size = "bacass", INPUTS[("bacass", 1)]
     est, _ = _fitted(cluster, wf, size)
