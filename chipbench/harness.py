@@ -2,9 +2,14 @@
 measured window, the compile counter, the device gate and the result line.
 
 Cells are found by name: a workload in ``BENCHMARK.json`` names a
-configuration (``configs/<name>.json``, whose ``runner`` key picks the
-module under ``runners/``) and a traffic mix (``traffic/<name>.json``);
-each per-layer metric is read by ``metrics/<name>.py``.
+configuration (its ``file``, ``configs/<name>.json``, whose ``runner`` key
+picks the module under ``runners/``) and a traffic mix
+(``traffic/<name>.json``); each per-layer metric is read by
+``metrics/<name>.py``.  So a new configuration, traffic mix or metric
+enters as files and entries alone.  A configuration file may carry
+``test_sizes``, the keys the benchmark's own tests change to run it on the
+CPU; nothing here reads it.  A closed-loop traffic file may carry a
+``faults`` block, which ``runners/executor.py`` describes.
 """
 from __future__ import annotations
 

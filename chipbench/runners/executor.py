@@ -1,25 +1,54 @@
 """Closed-loop execution of whole workflows through ``OnlineExecutor``.
 
 Set-up fits the estimator once on the local profiling runs and runs a few
-warm-up workflows, which compile every program a run uses.  The window
-then runs workflows one after another, each from a copy of the fitted
-estimator and with ground truth drawn from the seed.  A scheduling tick
-is one pass of the executor's event loop, read from the starts of the
-program's own ``tick_step`` spans.  The run in flight at the close is
-finished untimed when it is the only one, so there is always a whole run
-to check; otherwise it is stopped at its next tick.
+warm-up workflows (the configuration's ``warmup_runs``), which compile
+every program a run uses.  The window then runs workflows one after
+another, each from a copy of the fitted estimator and with ground truth
+drawn from the seed.  A scheduling tick is one pass of the executor's
+event loop, read from the starts of the program's own ``tick_step``
+spans.  The run in flight at the close is finished untimed when it is the
+only one, so there is always a whole run to check; otherwise it is
+stopped at its next tick.
+
+A closed-loop traffic file may carry a ``faults`` block::
+
+    "faults": {"crash": [[0, 0.25], [-1, 0.5]], "p_fail": 0.05,
+               "p_spread": 1.0}
+
+Each ``crash`` entry is (index into the grid's node names, fraction of
+the fault-free makespan M): that node crashes at that share of M and does
+not return.  M is the median makespan of set-up's fault-free warm-up
+runs; set-up then runs one more warm-up under faults.  Every run r gets
+the program's ``FaultInjector`` with those crash times, the per-attempt
+failure probability ``p_fail`` spread by ``p_spread``, and a seed drawn
+from the cell's seed and r.  The executor's own fault-tolerance settings
+(``rel_k``, ``max_attempts``, ``backoff_*``, ``strict``) are keywords of
+the configuration's ``executor`` block, which a faults traffic has to give
+``rel_k``, ``max_attempts``, ``backoff_base`` and ``backoff_cap``, since
+the reference reads them there.  The check then also holds every run to
+the fault semantics of ``reference_faults``.  Without the block no fault
+process exists and the runs are as before.
 """
 from __future__ import annotations
 
 import copy
 import math
+import statistics
+import sys
 
 import numpy as np
 
 import gen
+import reference_faults as rf
 from harness import BenchError, WindowClosed
 from reference import (Reference, cpu_weight, rel_gap, runtime_factors,
                        schedule_errors, tick_index)
+
+#: ``gen.rng_for`` tag of the fault injectors' seeds (1 and 2 are the
+#: deployment's data and each run's ground truth)
+FAULT_TAG = 3
+#: what a faults traffic needs the configuration's executor block to state
+FAULT_KEYS = {"rel_k", "max_attempts", "backoff_base", "backoff_cap"}
 
 
 class Runner:
@@ -48,16 +77,57 @@ class Runner:
         self.dag = gen.instances(cfg["deps"], samples)
         self.tasks = self._sched_tasks(self.dag)
         self.truths = {}
+        makespans = []
         for k in range(1, cfg["warmup_runs"] + 1):
-            rec.run = -k
-            truth = gen.eager_truth(cfg, self.data, self.dag, seed, -k)
-            self._executor(self._sched_tasks(self.dag), self.dag, truth,
-                           rec).run()
+            ex = self._warm_up(k)
+            makespans.append(ex.run().makespan)
+        self.faults = traffic.get("faults")
+        if self.faults is not None:
+            if not makespans:
+                raise BenchError("a faults traffic needs warmup_runs >= 1 "
+                                 "for its fault-free makespan")
+            self._fault_setup(ex.grid.names(), statistics.median(makespans))
+            k = cfg["warmup_runs"] + 1
+            self._warm_up(k, self.injector(-k)).run()
         self.runs: list[tuple[int, object, dict]] = []
+        # a faults run's final reliability state (host counts), for check
+        self.reliability: dict[int, object] = {}
         for r in range(2):      # the first runs' ground truth, before the window
             self._truth(r)
         self.shape = {"T": len(self.names), "N": len(self.types),
                       "Nb": len(self.types) + 1, "B": 1}
+
+    def _warm_up(self, k: int, faults=None):
+        """The executor of warm-up run ``-k``."""
+        self.rec.run = -k
+        truth = gen.eager_truth(self.cfg, self.data, self.dag, self.seed, -k)
+        return self._executor(self._sched_tasks(self.dag), self.dag, truth,
+                              self.rec, faults)
+
+    def _fault_setup(self, names: list[str], makespan: float) -> None:
+        """Crash times from the traffic's ``crash`` list and the fault-free
+        makespan."""
+        missing = FAULT_KEYS - set(self.cfg["executor"])
+        if missing:
+            raise BenchError("a faults traffic needs the configuration's "
+                             f"executor block to set {sorted(missing)}")
+        self.node_names = names
+        self.crash_at = {names[i]: frac * makespan
+                         for i, frac in self.faults["crash"]}
+        print(f"chipbench: fault-free makespan {makespan!r} s (median of "
+              f"{self.cfg['warmup_runs']} warm-up runs); crashes "
+              f"{self.crash_at!r}", file=sys.stderr, flush=True)
+
+    def fault_seed(self, run: int) -> int:
+        return int(gen.rng_for(self.seed, FAULT_TAG, run).integers(2 ** 31))
+
+    def injector(self, run: int):
+        """The program's fault process for run ``run``."""
+        from repro.sched.simulator import FaultInjector
+        f = self.faults
+        return FaultInjector(crash_at=self.crash_at, p_fail=f["p_fail"],
+                             p_spread=f["p_spread"],
+                             seed=self.fault_seed(run))
 
     def _run_local(self, name: str, size: float, cpu_factor: float) -> float:
         """The recorded local run of ``name`` at the partition ``size``."""
@@ -77,7 +147,7 @@ class Runner:
                 tasks[p].succ.append(tid)
         return tasks
 
-    def _executor(self, tasks, dag, truth, rec):
+    def _executor(self, tasks, dag, truth, rec, faults=None):
         from repro.online import OnlineExecutor
         from repro.sched.simulator import GridEngine
         cfg = self.cfg
@@ -87,7 +157,7 @@ class Runner:
             copy.deepcopy(self.est0), tasks,
             {tid: name for tid, (name, _) in dag.items()}, cfg["input_gb"],
             grid, lambda tid, node: truth[(tid, grid.type_of(node).name)],
-            tracer=rec, **cfg["executor"])
+            tracer=rec, faults=faults, **cfg["executor"])
 
     def _truth(self, run: int) -> dict:
         if run not in self.truths:
@@ -113,11 +183,16 @@ class Runner:
             while not win.closed():
                 rec.run = r
                 truth = self._truth(r)
+                faults = (self.injector(r) if self.faults is not None
+                          else None)
                 with rec.span("run"):
                     with rec.span("run_setup"):
-                        ex = self._executor(self.tasks, self.dag, truth, rec)
+                        ex = self._executor(self.tasks, self.dag, truth, rec,
+                                            faults)
                     trace = ex.run()
                 self.runs.append((r, trace, truth))
+                if faults is not None:
+                    self.reliability[r] = ex.est.reliability
                 r += 1
         except WindowClosed:
             pass
@@ -211,8 +286,47 @@ class Runner:
                 y_prog = cp["y_local"]
             est_gap = max(est_gap, _est_gap(prog, rp))
             dadj_gap = max(dadj_gap, rel_gap(y_prog, rp["y_local"]))
-        return {"runs_checked": len(self.runs), "schedule_errors": sched,
-                "estimate_gap": est_gap, "deadjust_gap": dadj_gap}
+        out = {"runs_checked": len(self.runs), "schedule_errors": sched,
+               "estimate_gap": est_gap, "deadjust_gap": dadj_gap}
+        if self.faults is not None:
+            out.update(self._check_faults())
+        return out
+
+    def _check_faults(self) -> dict:
+        """The fault semantics of every checked run, against
+        ``reference_faults``: schedule errors under the crashes and the
+        backoff, the gap of the run's final reliability factors, and the
+        runs that lost fewer nodes than the traffic crashes, plus one when
+        no attempt of the window failed by itself."""
+        ex = self.cfg["executor"]
+        errors, gap, short, attempt_failed = 0, 0.0, 0, False
+        for r, trace, _ in self.runs:
+            recs = [{"id": x.id, "node": x.node, "start": x.start,
+                     "end": x.end} for x in trace.records]
+            cens = [{"id": c.id, "node": c.node, "start": c.start,
+                     "lost_at": c.lost_at, "reason": c.reason}
+                    for c in trace.censored]
+            errors += rf.fault_schedule_errors(
+                recs, cens, self.crash_at, ex["max_attempts"],
+                len(trace.observations),
+                (ex["backoff_base"], ex["backoff_cap"]))
+            succ, fail = rf.attempt_counts(recs, cens)
+            rel = self.reliability[r]
+            prog = (np.ones(len(self.node_names)) if rel is None
+                    else rel.factors(self.node_names, ex["rel_k"]))
+            gap = max(gap, rel_gap(prog, rf.reliability_factors(
+                self.node_names, succ, fail, ex["rel_k"])))
+            attempt_failed |= any(c["reason"] == "attempt" for c in cens)
+            short += trace.lost_nodes < len(self.crash_at)
+        short += self.faults["p_fail"] > 0 and not attempt_failed
+        runs = [t for _, t, _ in self.runs]
+        print("chipbench: per run failures "
+              f"{[t.failures for t in runs]}, retries "
+              f"{[t.retries for t in runs]}, lost nodes "
+              f"{[t.lost_nodes for t in runs]}, re-plans "
+              f"{[t.replans for t in runs]}", file=sys.stderr)
+        return {"fault_schedule_errors": errors, "reliability_gap": gap,
+                "fault_shortfall": int(short)}
 
 
 def _est_gap(prog, rp) -> float:

@@ -17,24 +17,26 @@ REPO = BENCH.parent
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 
-#: what the cells' configurations change to fit a test run
-TINY = {"nfcore-eager-ds1-5n": {"warmup_runs": 1}}
+
+def load_bench(root: Path = REPO) -> dict:
+    return json.loads((root / "BENCHMARK.json").read_text())
 
 
-def load_bench() -> dict:
-    return json.loads((REPO / "BENCHMARK.json").read_text())
-
-
-def make_tree(dst: Path) -> Path:
-    """A benchmark tree under ``dst``: this benchmark's files, with every
-    configuration changed by its ``TINY`` entry."""
-    shutil.copytree(BENCH, dst / BENCH.name, ignore=shutil.ignore_patterns(
-        "out", "tests", "__pycache__"))
-    bench = load_bench()
+def make_tree(dst: Path, src: Path = REPO) -> Path:
+    """A benchmark tree under ``dst``: the benchmark's files and
+    ``BENCHMARK.json`` of the checkout ``src``, each configuration of it
+    changed to fit a test run by its own file.  A configuration file brings
+    its test sizes as an optional ``test_sizes`` object, whose keys replace
+    the file's top-level keys here (absent: nothing changes); the harness
+    and the runners never read it."""
+    shutil.copytree(src / BENCH.name, dst / BENCH.name,
+                    ignore=shutil.ignore_patterns("out", "tests",
+                                                  "__pycache__"))
+    bench = load_bench(src)
     for conf in bench["configs"]:
         path = dst / conf["file"]
         cfg = json.loads(path.read_text())
-        cfg.update(TINY[conf["name"]])
+        cfg.update(cfg.get("test_sizes", {}))
         path.write_text(json.dumps(cfg))
     (dst / "BENCHMARK.json").write_text(json.dumps(bench))
     return dst

@@ -1,11 +1,25 @@
 """With the timed path broken underneath, a run's ``correct`` comes out
 false; so does a run with the bfloat16 control in the program's place.
-Each cell is run on the CPU with the device gate skipped."""
+Each cell of the executor runner in ``BENCHMARK.json`` is run on the CPU
+with the device gate skipped."""
 import dataclasses
+import json
 
 import pytest
 
 import repro.core.tick as tick
+from conftest import REPO, load_bench
+
+
+def _executor_cells():
+    bench = load_bench()
+    runner = {c["name"]: json.loads((REPO / c["file"]).read_text())["runner"]
+              for c in bench["configs"]}
+    return [w["name"] for w in bench["workloads"]
+            if runner[w["config"]] == "executor"]
+
+
+CELLS = _executor_cells()
 
 
 def _unchanged(orig):
@@ -41,9 +55,8 @@ def _altered(orig):
     return step
 
 
-FAULTS = [("eager-ds1-5n.runs8", _unchanged),
-          ("eager-ds1-5n.runs8", _bias_dropped),
-          ("eager-ds1-5n.runs8", _altered)]
+FAULTS = [(c, f) for c in CELLS for f in (_unchanged, _bias_dropped,
+                                           _altered)]
 
 
 @pytest.mark.parametrize("cell,fault", FAULTS,
@@ -57,7 +70,7 @@ def test_fault_fails(tree, run_cell, monkeypatch, cell, fault):
     assert any(c["value"] > c["limit"] for c in line["checks"].values())
 
 
-@pytest.mark.parametrize("cell", ["eager-ds1-5n.runs8"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_bfloat16_control_fails(tree, run_cell, cell):
     """With the reference computed in bfloat16 in the program's place, the
     run comes out not correct, by a number over its limit."""
