@@ -268,10 +268,13 @@ class OnlineExecutor:
         dispatch, finish, observe — with interval coverage and PIT —
         predict, surprise, speculation, fault, retry, backoff,
         node_down/up, stranded) with sim- and wall-time stamps, plus
-        wall-clock spans around the HEFT (re-)plan and the estimator's
-        jitted predict/update dispatches (the tracer is attached to the
-        grid and the estimator too).  Tracing is strictly read-only:
-        ``run()`` output is bit-identical with and without it
+        wall-clock spans around the HEFT (re-)plan (``plan``), the
+        surprise gate, the handling of each lost node or failed attempt
+        (``fault``, ``n`` = the attempts it lost), the frontier re-plan a
+        fault or a rejoin causes (``replan``, around its ``plan``) and the
+        estimator's jitted predict/update dispatches (the tracer is
+        attached to the grid and the estimator too).  Tracing is strictly
+        read-only: ``run()`` output is bit-identical with and without it
         (test-enforced, same pattern as the ``faults=None`` proof).
     """
 
@@ -763,9 +766,10 @@ class OnlineExecutor:
             ext = {**done, **{k: max(v, t_now)
                               for k, v in expected_finish.items()
                               if k not in done}}
-            queues = self._plan(unstarted, t_now, ext,
-                                frontier_exact=not stranded)
-            trace.replans += 1
+            with tr.span("replan", t_sim=t_now, n=len(unstarted)):
+                queues = self._plan(unstarted, t_now, ext,
+                                    frontier_exact=not stranded)
+                trace.replans += 1
 
         def node_down(node: str, t_now: float) -> None:
             """A crash or outage start: mask the node, kill its running
@@ -898,7 +902,10 @@ class OnlineExecutor:
             if kind == "down":
                 t = max(t, end)
                 if self.grid.nodes[a].alive:
-                    node_down(a, t)
+                    lost = sum(e[0] == a for atts in running.values()
+                               for e in atts)
+                    with tr.span("fault", t_sim=t, n=lost):
+                        node_down(a, t)
                 continue
             if kind == "up":
                 t = max(t, end)
@@ -909,9 +916,10 @@ class OnlineExecutor:
                 continue                 # stale event of a killed attempt
             t = end
             if kind == "fail":
-                if lose_attempt(tid, ev_seq, t, "attempt"):
-                    schedule_retry(tid, node, t)
-                    replan_frontier(t)
+                with tr.span("fault", t_sim=t, n=1):
+                    if lose_attempt(tid, ev_seq, t, "attempt"):
+                        schedule_retry(tid, node, t)
+                        replan_frontier(t)
                 continue
             # batch every completion landing on this tick: multi-node
             # observations arriving together are absorbed by ONE scanned

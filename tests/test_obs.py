@@ -88,7 +88,9 @@ def test_fused_run_spans_inside_the_tick():
     """A traced fused run splits each completion tick into its surprise
     gate, the engine's tick (with the estimates' fetch nested in it) and
     the bias statistics' fetch; every span records its parent and the
-    thread's CPU time over it."""
+    thread's CPU time over it.  The fault path nests too: a frontier
+    re-plan inside the fault that caused it (or at top level after a
+    rejoin), its HEFT plan inside the re-plan."""
     log = EventLog()
     _faulty(tracer=log, fused=True).run()
     ticks = len(log.filter("predict"))          # one per completion tick
@@ -100,6 +102,8 @@ def test_fused_run_spans_inside_the_tick():
         parents[e.data["phase"]].add(e.data["parent"])
         assert 0.0 <= e.data["cpu_s"]
     assert parents.pop("tick_fetch") == {"tick_step"}
+    assert parents.pop("replan") == {"fault", None}
+    assert parents.pop("plan") == {"replan", None}
     assert all(p == {None} for p in parents.values()), parents
     # the nested fetch lies inside its tick_step on the wall clock
     steps = log.spans("tick_step")
