@@ -78,14 +78,22 @@ def test_tick_step_full_sweep_ceiling(one_chip):
 
 
 def test_gate_table_full_sweep_ceiling(one_chip):
-    """The surprise gate's table of the same model, float32."""
-    from repro.core.tick import gate_table
+    """The host view of the same state, float32: estimates, the surprise
+    gate's table and the bias statistics packed into one buffer."""
+    from repro.core.tick import host_view
     est, _names, nodes = scale_estimator(4096, 256)
     state, _ = build_state(est, nodes)
+    s = _abstract(state, one_chip)
     size = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip,
                                 weak_type=True)
-    compiled = gate_table.lower(_abstract(state.model, one_chip),
-                                size).compile()
+    compiled = host_view.lower(s.factors, s.factors, s.model,
+                               s.bias_counts, s.bias_log_sum,
+                               s.bias_log_sq, size).compile()
+    t, n = state.factors.shape
+    nb = state.bias_counts.shape[1]
+    out = compiled.out_info
+    assert out.shape == (2 * t * n + 6 * t + 3 * t * nb,)
+    assert out.dtype == jnp.float32
     assert 0 < _hbm(compiled) < HBM_BYTES
 
 

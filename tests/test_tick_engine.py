@@ -249,7 +249,7 @@ def test_gate_dispatches_nothing_after_a_tick(cluster, monkeypatch):
 def test_engine_program_names(cluster, monkeypatch):
     """The benchmark's device-time readers find the tick's program by the
     fragment ``tick_core`` of its name: the tick the engine runs has it,
-    and the gate table's program, run at build and after each tick, has
+    and the host view's program, run at build and after each tick, has
     not."""
     import re
     from repro.core import tick
@@ -257,7 +257,7 @@ def test_engine_program_names(cluster, monkeypatch):
     est, by_name = _fitted(cluster, wf, size)
     nodes = [nt.name for nt in target_nodes()]
     ran = []
-    for attr in ("tick_step", "gate_table"):
+    for attr in ("tick_step", "host_view"):
         jitted = getattr(tick, attr)
 
         def spy(*args, _jitted=jitted, _attr=attr, **kw):
@@ -268,9 +268,117 @@ def test_engine_program_names(cluster, monkeypatch):
         monkeypatch.setattr(tick, attr, spy)
     engine = TickEngine(est, nodes, size=size)
     engine.observe_batch([(list(by_name)[0], nodes[0], size, 30.0)])
-    assert [a for a, _ in ran] == ["gate_table", "tick_step", "gate_table"]
+    assert [a for a, _ in ran] == ["host_view", "tick_step", "host_view"]
     for attr, program in ran:
         assert ("tick_core" in program) == (attr == "tick_step"), program
+
+
+def _bits(a):
+    return a.shape, a.dtype, a.tobytes()
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+def test_host_view_is_bitwise_the_tick_outputs(cluster, request,
+                                               monkeypatch, dtype):
+    """At build and after every tick, the engine's estimates, gate table
+    and bias statistics, all views of one copied buffer, are bitwise the
+    float64 widening of what ``tick_step`` returned, of the gate table of
+    the model it left, and of the bias leaves of the state it left; the
+    six views do not overlap."""
+    from repro.core import tick
+    if dtype == "float32":
+        request.getfixturevalue("_f32")
+    wf, size = "eager", INPUTS[("eager", 1)]
+    est, by_name = _fitted(cluster, wf, size)
+    nodes = [nt.name for nt in target_nodes()]
+    engine = TickEngine(est, nodes, size=size)
+    assert engine.state.factors.dtype == np.dtype(dtype)
+    want = []
+
+    def widened(state, mean, std):
+        # copies: the next tick consumes (donates) this state
+        return [np.array(a, np.float64) for a in (
+            mean, std, tick._gate_table(state.model, size),
+            state.bias_counts, state.bias_log_sum, state.bias_log_sq)]
+
+    def spy(state, obs, size_, host_deadjust):
+        out = real(state, obs, size_, host_deadjust=host_deadjust)
+        want.append(widened(out[0], out[1], out[2]))
+        return out
+
+    real = tick.tick_step
+    monkeypatch.setattr(tick, "tick_step", spy)
+    want.append(widened(engine.state,
+                        *predict_state(engine.state, size)))
+    names = list(by_name)
+    batches = [[(names[0], nodes[0], size, 30.0)],
+               [(names[-1], nodes[1], size, 12.0),
+                (names[4], nodes[2], size, 55.0)],
+               [(names[-1], nodes[1], size, 16.0)]]
+    for tick_no in range(len(batches) + 1):
+        b = engine._bias
+        got = [engine._mean, engine._std, engine._gate, b.counts,
+               b.log_sum, b.log_sq]
+        assert [_bits(g) for g in got] == [_bits(w) for w in want[-1]]
+        for k, g in enumerate(got):
+            assert g.flags.c_contiguous
+            assert not any(np.shares_memory(g, h) for h in got[k + 1:])
+        if tick_no < len(batches):
+            engine.observe_batch(batches[tick_no])
+    assert len(want) == len(batches) + 1
+    assert np.any(engine._bias.counts > 0)
+
+
+def test_one_copy_per_tick(cluster, monkeypatch):
+    """One ``observe_batch`` dispatches the host view once and makes one
+    copy to the host, of that buffer, whose byte count the ``tick_fetch``
+    span carries.  The CPU backend does not enforce the transfer guard,
+    so the explicit copies are counted, and numpy, as the engine sees it,
+    refuses a device array (an implicit copy)."""
+    from repro.core import tick
+    from repro.obs import EventLog
+    wf, size = "eager", INPUTS[("eager", 1)]
+    est, by_name = _fitted(cluster, wf, size)
+    nodes = [nt.name for nt in target_nodes()]
+    log = EventLog()
+    engine = TickEngine(est, nodes, size=size, tracer=log)
+    views, copied = [], []
+    real_view, real_get = tick.host_view, jax.device_get
+
+    def view(*args, **kw):
+        views.append(real_view(*args, **kw))
+        return views[-1]
+
+    def device_get(x):
+        copied.append(x)
+        return real_get(x)
+
+    class HostOnlyNumpy:
+        def __getattr__(self, name):
+            f = getattr(np, name)
+            if name not in ("asarray", "array"):
+                return f
+
+            def host_only(a, *args, **kw):
+                assert not isinstance(a, jax.Array), "implicit copy"
+                return f(a, *args, **kw)
+            return host_only
+
+    monkeypatch.setattr(tick, "host_view", view)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(tick, "np", HostOnlyNumpy())
+    names = list(by_name)
+    with jax.transfer_guard_device_to_host("disallow"):
+        engine.observe_batch([(names[0], nodes[0], size, 30.0),
+                              (names[-1], nodes[1], size, 12.0)])
+    monkeypatch.undo()
+    assert len(views) == 1
+    assert len(copied) == 1 and copied[0] is views[0]
+    t, n = engine._mean.shape
+    nb = engine._bias.counts.shape[1]
+    fetch, = log.spans("tick_fetch")
+    assert fetch.data["nbytes"] == views[0].nbytes == \
+        (2 * t * n + 6 * t + 3 * t * nb) * 8
 
 
 def test_tick_step_donates_and_predict_state_matches(cluster):
