@@ -176,7 +176,7 @@ def run_loop(device, n_samples: int = 8):
             est, tasks, task_name, size, grid,
             lambda tid, node: truth_tab[(tid, grid.type_of(node).name)],
             online=True, confidence=0.9, speculate=True, risk_k=1.0,
-            spec_tail=0.8, fused=True)
+            spec_tail=0.8)
         return ex.run()
 
 

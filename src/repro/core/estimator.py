@@ -494,7 +494,11 @@ class LotaruEstimator(_BiasLayer):
         observations in one tick see the same model mean — sequential
         ``observe`` calls refresh it in between (batches over distinct
         tasks are exactly equivalent to sequential calls).  Returns the
-        de-adjusted local runtimes in input order."""
+        de-adjusted local runtimes in input order.
+
+        This is the offline API and the host reference of the fused tick:
+        ``OnlineExecutor`` ticks through ``repro.core.tick.TickEngine``,
+        which is held equal to this path to 1e-12 under x64."""
         obs = [_as_obs_tuple(o) for o in observations]
         if not obs:
             return []

@@ -1,13 +1,14 @@
 """The fused tick: observe → update → bias scatter → re-predict in ONE
 jitted, donated-buffer dispatch over ``EstimatorState``.
 
-The legacy online path is four separate dispatches per simulation tick
-(``update_task_batch_stream`` scan, per-row ``slice_task_model``
-writebacks, a host-side ``BiasModel.update`` scatter, and a dirty-row
-``predict_matrix`` re-predict), stitched together by Python in
-``LotaruEstimator.observe_batch``.  ``tick_step`` fuses the whole
+The estimator's host reference, ``LotaruEstimator.observe_batch``, is
+four separate dispatches per simulation tick (``update_task_batch_stream``
+scan, per-row ``slice_task_model`` writebacks, a host-side
+``BiasModel.update`` scatter, and a dirty-row ``predict_matrix``
+re-predict), stitched together by Python.  ``tick_step`` fuses the whole
 sequence into one ``state -> state`` function the scheduler can sit
-inside — and, because it is pure over a registered pytree, one that
+inside — every online ``OnlineExecutor`` run ticks on it through
+``TickEngine`` — and, because it is pure over a registered pytree, one that
 ``vmap``s over a leading workflow axis (``repro.online.fleet``) and
 shards under ``jax.sharding.NamedSharding``.
 
@@ -25,7 +26,7 @@ Observation batches are packed as an ``(B, 8)`` array::
   device / fleet path) it is recomputed on device from ``y_raw`` and
   the tick-start bias, and the packed value is ignored;
 * ``med``/``spr`` — the row's refreshed median/MAD (order statistics
-  live host-side in the ``SampleLog``, exactly as in the legacy path);
+  live host-side in the ``SampleLog``, exactly as in the host reference);
 * ``valid`` — padding mask (0 rows are no-ops), so fleet batches can
   pad ragged per-workflow ticks to one shape.
 
@@ -235,16 +236,18 @@ def _layout(shapes):
 
 
 class TickEngine:
-    """Executor-facing driver of the fused tick.
+    """Executor-facing driver of the fused tick: the one online tick path
+    of ``OnlineExecutor``.
 
     Owns an ``EstimatorState`` snapshot of a fitted estimator and
     replaces the estimator's per-tick surface (``observe_batch`` +
     ``predict_matrix`` + the scalar interval/PIT/bias consumers) with
-    ``tick_step`` outputs, while keeping the host-side pieces the legacy
-    path keeps host-side: the raw-sample ``SampleLog`` (order
-    statistics), the de-adjust of measured runtimes (bit-compatible
-    float64, ``host_deadjust=True``) and the Beta-Binomial reliability
-    plane (consumed by the scheduler, not the tick).  Each tick's
+    ``tick_step`` outputs, while keeping the pieces the estimator's host
+    reference (its own ``observe_batch``) keeps host-side: the
+    raw-sample ``SampleLog`` (order statistics), the de-adjust of
+    measured runtimes (bit-compatible float64, ``host_deadjust=True``)
+    and the Beta-Binomial reliability plane (consumed by the scheduler,
+    not the tick).  Each tick's
     estimates, gate table and bias statistics reach the host in one copy
     of the ``host_view`` buffer; the scalar interval/PIT consumers read
     views of it and dispatch nothing.
@@ -298,8 +301,8 @@ class TickEngine:
         return list(self.names.nodes)
 
     def observe_batch(self, observations) -> list[float]:
-        """One fused tick: de-adjust host-side (bitwise the legacy
-        float64 math), append the raw history, then dispatch ONE
+        """One fused tick: de-adjust host-side (bitwise the host
+        reference's float64 math), append the raw history, then dispatch ONE
         ``tick_step`` that absorbs the stream, scatters the bias
         residuals and re-predicts the full (T, N) matrix, then ONE
         ``host_view`` and its one copy to the host."""
@@ -469,7 +472,7 @@ class TickEngine:
     def finalize(self) -> None:
         """Fold the final state back into the wrapped estimator (batch
         cache, touched scalar models, bias posterior) — after this the
-        legacy OO surface continues bit-compatibly."""
+        estimator's OO surface continues bit-compatibly."""
         if self._rel_dirty:
             self._sync_reliability()
         state = EstimatorState(

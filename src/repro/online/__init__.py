@@ -4,7 +4,8 @@ Closes the loop the paper leaves open: incremental conjugate posterior
 updates (``repro.core.blr.update_task_batch``), an observation stream fed
 back through ``LotaruEstimator.observe`` / ``LotaruML.observe``, and an
 event-driven execution engine that interleaves run → observe → re-predict
-→ re-schedule over grid-engine-style heterogeneous nodes.
+→ re-schedule over grid-engine-style heterogeneous nodes, each online
+tick one fused ``repro.core.tick.TickEngine`` dispatch.
 """
 from .buffer import Observation, ObservationBuffer
 from .executor import (CensoredRun, ExecutionTrace, OnlineExecutor, TaskRun,

@@ -3,12 +3,13 @@ re-schedule).
 
 The closed loop the paper motivates but never builds: a HEFT plan from the
 locally-fitted estimates is executed on grid-engine-style nodes; every
-finished task's realised runtime is fed back through
-``LotaruEstimator.observe`` (incremental conjugate update, O(d²)); and when
-a runtime falls outside its predictive interval — the model was *surprised*
-— the not-yet-started frontier is re-planned with ``heft_schedule_array``
-over the refreshed estimate matrix, with node/task availability floors so
-running work is never disturbed.
+tick's finished tasks are fed back through one ``TickEngine`` tick
+(``repro.core.tick``: incremental conjugate update, bias scatter and
+re-predict in one dispatch); and when a runtime falls outside its
+predictive interval — the model was *surprised* — the not-yet-started
+frontier is re-planned with ``heft_schedule_array`` over the refreshed
+estimate matrix, with node/task availability floors so running work is
+never disturbed.
 
 The same loop with ``online=False`` executes the static plan with frozen
 predictions, which is the baseline every benchmark compares against.
@@ -27,6 +28,7 @@ from dataclasses import asdict, dataclass, field
 
 import numpy as np
 
+from repro.core.tick import TickEngine
 from repro.obs.calibration import running_median
 from repro.obs.trace import NULL_TRACER
 from repro.sched.heft import (CommCosts, SchedTask, _topo_order,
@@ -174,8 +176,10 @@ class OnlineExecutor:
 
     Parameters
     ----------
-    estimator : LotaruEstimator-like (``predict_matrix``, ``observe``,
-        ``predict_interval_node``, ``task_names``)
+    estimator : a fitted ``LotaruEstimator``.  An online run drives it
+        through a ``TickEngine`` (one jitted tick per completion batch)
+        and writes the final state back into it when the run ends; the
+        static loop reads its ``predict_matrix`` once.
     tasks : dict[str, SchedTask] — instance-level DAG
     task_name : dict[str, str] — instance id → abstract estimator task
     size : float — the workflow's input size (shared by all instances)
@@ -192,7 +196,6 @@ class OnlineExecutor:
         re-plan after a surprise prices placements by the *current*
         posterior widths — pairs whose bias is still unsettled look
         expensive until evidence narrows them.
-    replan_cooldown : minimum completions between two re-plans
     speculate : couple the bias posterior to straggler mitigation — a
         still-running task that has outrun its dispatch-time envelope
         (mean + spec_k·sigma) on a node whose learned (task, node) bias
@@ -204,9 +207,9 @@ class OnlineExecutor:
         slow for the task (pairs look undrifted until observed)
     spec_tail : admission statistic for the drift check.  ``None``
         (default) compares the bias *point estimate* against
-        ``bias_drift`` (the PR 3 behaviour, needs ``bias_point``); a
-        float in (0, 1) instead requires the bias posterior's tail mass
-        ``P(bias > bias_drift)`` to reach it (needs ``bias_tail_mass``).
+        ``bias_drift`` (the PR 3 behaviour); a float in (0, 1) instead
+        requires the bias posterior's tail mass ``P(bias > bias_drift)``
+        to reach it.
         Values above 0.5 are strictly more conservative than the point
         estimate — a single noisy residual can move the posterior mean
         across the drift line, but not drag most of its mass across —
@@ -272,8 +275,9 @@ class OnlineExecutor:
         surprise gate, the handling of each lost node or failed attempt
         (``fault``, ``n`` = the attempts it lost), the frontier re-plan a
         fault or a rejoin causes (``replan``, around its ``plan``) and the
-        estimator's jitted predict/update dispatches (the tracer is
-        attached to the grid and the estimator too).  Tracing is strictly
+        engine's tick (``tick_step`` with its ``tick_fetch``, then
+        ``bias_fetch``; the tracer is attached to the grid and the
+        estimator too).  Tracing is strictly
         read-only: ``run()`` output is bit-identical with and without it
         (test-enforced, same pattern as the ``faults=None`` proof).
     """
@@ -282,16 +286,22 @@ class OnlineExecutor:
                  task_name: dict[str, str], size: float, grid: GridEngine,
                  runtime_fn, *, online: bool = True,
                  confidence: float = 0.9, risk_k: float = 0.0,
-                 replan_cooldown: int = 0, speculate: bool = True,
+                 speculate: bool = True,
                  spec_k: float = 2.0, bias_drift: float = 1.15,
                  spec_tail: float | None = None,
                  faults=None, max_attempts: int = 4,
                  backoff_base: float = 1.0, backoff_cap: float = 30.0,
                  rel_k: float | None = None, strict: bool = True,
-                 tracer=None, fused: bool = False,
-                 incremental_replan: bool | None = None,
+                 tracer=None, fused: bool = True,
                  edge_gb: dict[tuple[str, str], float] | None = None,
                  comm_aware: bool = True):
+        # accepted only as True, for configuration files that still pass it
+        if not fused:
+            raise ValueError(
+                "fused=False: the host per-tick path was removed from the "
+                "executor, which always drives the TickEngine; "
+                "LotaruEstimator.observe_batch remains the offline and "
+                "reference API")
         if spec_tail is not None and not 0.0 < spec_tail < 1.0:
             raise ValueError(f"spec_tail must be in (0, 1), got {spec_tail}")
         if max_attempts < 1:
@@ -308,7 +318,6 @@ class OnlineExecutor:
         self.online = online
         self.confidence = confidence
         self.risk_k = risk_k
-        self.replan_cooldown = replan_cooldown
         self.speculate = speculate
         self.spec_k = spec_k
         self.bias_drift = bias_drift
@@ -324,13 +333,10 @@ class OnlineExecutor:
             # one log observes the whole stack: grid membership churn and
             # the estimator's predict/update spans land in the same trace
             grid.tracer = self.tracer
-            if hasattr(estimator, "set_tracer"):
-                estimator.set_tracer(self.tracer)
+            estimator.set_tracer(self.tracer)
         # track attempt outcomes in the reliability posterior whenever a
-        # fault process exists or reliability pricing is on (and the
-        # estimator has the availability plane at all)
-        self._track_rel = ((faults is not None or rel_k is not None)
-                           and hasattr(estimator, "record_attempt"))
+        # fault process exists or reliability pricing is on
+        self._track_rel = faults is not None or rel_k is not None
         self.node_names = grid.names()
         # stable node-type column order for the estimate matrix
         seen: dict[str, None] = {}
@@ -344,23 +350,13 @@ class OnlineExecutor:
         task_rows = {nm: i for i, nm in enumerate(estimator.task_names())}
         for tid, nm in task_name.items():
             self._row[tid] = task_rows[nm]
-        # fused mode: the per-tick estimator surface is served by a
-        # TickEngine (one jitted tick_step per completion batch) instead
-        # of the estimator's host-orchestrated observe/predict sequence;
-        # the final state is written back into the estimator at run end
-        self._engine = None
-        if fused and online:
-            from repro.core.tick import TickEngine
-            self._engine = TickEngine(estimator, self.type_names,
-                                      size=self.size, tracer=self.tracer)
-        self._api = self._engine if self._engine is not None else estimator
-        # incremental re-planning (defaults on with the fused tick):
-        # upward ranks over the FULL instance graph are cached and only
-        # the dirty ancestor chains re-ranked per re-plan — bitwise equal
-        # to the from-scratch rank (oracle-tested), because a successor
-        # of an unstarted task is always itself unstarted
-        self._incremental = ((fused if incremental_replan is None
-                              else incremental_replan) and online)
+        # an online run's per-tick estimator surface is a TickEngine (one
+        # jitted tick_step per completion batch); the final state is
+        # written back into the estimator at run end.  The static loop
+        # never ticks and reads the estimator itself.
+        self._api = (TickEngine(estimator, self.type_names, size=self.size,
+                                tracer=self.tracer)
+                     if online else estimator)
         self._ids = list(tasks)
         self._id_idx = {tid: i for i, tid in enumerate(self._ids)}
         # edges to ids outside the instance set (external/unsatisfiable
@@ -402,17 +398,14 @@ class OnlineExecutor:
                    self.backoff_cap)
 
     def _rel_factors(self) -> np.ndarray:
-        """(N,) per-node-instance reliability price multipliers (all-ones
-        when the estimator has no availability plane)."""
-        if hasattr(self._api, "reliability_factors"):
-            return np.asarray(self._api.reliability_factors(
-                self.node_names, self.rel_k), np.float64)
-        return np.ones(len(self.node_names), np.float64)
+        """(N,) per-node-instance reliability price multipliers."""
+        return np.asarray(self._api.reliability_factors(
+            self.node_names, self.rel_k), np.float64)
 
     # ---- planning ---------------------------------------------------------
     def _estimates(self, with_std: bool = True):
-        """Current (abstract-task × node-type) mean/std matrices.  After an
-        ``observe`` only the dirty row is recomputed (matrix row cache).
+        """Current (abstract-task × node-type) mean/std matrices: the
+        engine's last tick online, the frozen first estimate static.
         ``with_std=False`` returns ``(mean, None)`` and skips the bias
         widening — the mean-only fast path a risk-neutral plan takes."""
         return self._api.predict_matrix(self.type_names, self.size,
@@ -514,8 +507,10 @@ class OnlineExecutor:
                 {(idx[p], idx[s]): g for (p, s), g in self.edge_gb.items()
                  if p in idx and s in idx},
                 spg)
+        # online re-plans reuse the cached full-graph ranks (bitwise equal
+        # to the from-scratch rank, oracle-tested)
         rank = (self._incremental_rank(unstarted, mean, std, rf, spg)
-                if self._incremental and frontier_exact else None)
+                if self.online and frontier_exact else None)
         if comm is None:
             task_ready = np.array([
                 max((ext_finish.get(p, t_now)
@@ -578,7 +573,6 @@ class OnlineExecutor:
         heap: list[tuple[float, int, str, str, str | None]] = []
         seq = 0
         t = 0.0
-        cooldown = 0
         attempt_no: dict[str, int] = {}    # attempts dispatched per task
         fail_count: dict[str, int] = {}    # attempts lost per task
         retry_at: dict[str, float] = {}    # backoff floor per task
@@ -814,13 +808,6 @@ class OnlineExecutor:
             when ``spec_tail`` is set — the posterior tail mass
             ``P(bias > bias_drift) >= spec_tail``, which no single noisy
             residual can satisfy."""
-            bias_point = getattr(self._api, "bias_point", None)
-            tail_mass = getattr(self._api, "bias_tail_mass", None)
-            if self.spec_tail is not None:
-                if tail_mass is None:
-                    return
-            elif bias_point is None:
-                return
             for tid, attempts in list(running.items()):
                 if tid in done or tid in speculated or len(attempts) != 1:
                     continue
@@ -830,10 +817,12 @@ class OnlineExecutor:
                 if t_now < rec.start + envelope:
                     continue                      # not overdue yet
                 if self.spec_tail is not None:
-                    if tail_mass(rec.name, rec.node_type,
-                                 self.bias_drift) < self.spec_tail:
+                    if self._api.bias_tail_mass(
+                            rec.name, rec.node_type,
+                            self.bias_drift) < self.spec_tail:
                         continue    # posterior mass not behind the drift
-                elif bias_point(rec.name, rec.node_type) < self.bias_drift:
+                elif self._api.bias_point(rec.name,
+                                          rec.node_type) < self.bias_drift:
                     continue                      # node not drifted for it
                 node = attempts[0][0]
                 idle = [n for n in self.grid.idle(t_now) if n != node]
@@ -958,14 +947,12 @@ class OnlineExecutor:
                             node=crec.node, start=crec.start,
                             runtime=crec.runtime,
                             spec_win=sr is not None and sr.node == cnode)
-            cooldown = max(0, cooldown - len(completions))
             if self.online:
                 # surprise gates BEFORE the update: was each realised
                 # runtime outside what the dispatch-time posterior (the
                 # tick-start belief) considered likely?
                 batch = []
                 gates = []
-                pit_of = getattr(self._api, "predict_pit_node", None)
                 with tr.span("surprise_gate", t_sim=t, n=len(completions)):
                     for ctid, cnode, _ in completions:
                         run = trace.records[rec_idx[ctid]]
@@ -980,8 +967,8 @@ class OnlineExecutor:
                             # the tick-start belief, read-only: the same
                             # interval the surprise gate consumed, plus the
                             # PIT of the realised runtime under it
-                            pit = (pit_of(name, ntype, self.size, run.runtime)
-                                   if pit_of is not None else None)
+                            pit = self._api.predict_pit_node(
+                                name, ntype, self.size, run.runtime)
                             tr.emit("observe", t_sim=t, task=ctid, name=name,
                                     node=run.node, node_type=ntype,
                                     runtime=run.runtime,
@@ -997,7 +984,7 @@ class OnlineExecutor:
                                                                local_rts):
                     trace.observations.record(name, ntype, self.size,
                                               runtime, local_rt, time=t)
-                mean, std = self._estimates()     # dirty-row refresh only
+                mean, std = self._estimates()
                 trace.surprises += sum(gates)
                 if tr.enabled:
                     tr.emit("predict", t_sim=t, n_obs=len(batch),
@@ -1005,14 +992,13 @@ class OnlineExecutor:
                 unstarted = [x for x in self.tasks
                              if x not in started and x not in done
                              and x not in stranded]
-                if any(gates) and unstarted and cooldown == 0:
+                if any(gates) and unstarted:
                     ext = {**done, **{k: max(v, t)
                                       for k, v in expected_finish.items()
                                       if k not in done}}
                     queues = self._plan(unstarted, t, ext,
                                         frontier_exact=not stranded)
                     trace.replans += 1
-                    cooldown = self.replan_cooldown
                 if self.speculate:
                     speculate_stragglers(t)
         trace.makespan = max(done.values()) if done else 0.0
@@ -1030,11 +1016,11 @@ class OnlineExecutor:
                     speculations=trace.speculations,
                     spec_wins=trace.spec_wins, failures=trace.failures,
                     retries=trace.retries, mpe=trace.final_mpe())
-        if self._engine is not None:
+        if self.online:
             # fold the device-resident state back into the estimator so
             # the OO surface (scalar predicts, save/load) picks up from
-            # exactly where the fused ticks left off
-            self._engine.finalize()
+            # exactly where the engine's ticks left off
+            self._api.finalize()
         return trace
 
 

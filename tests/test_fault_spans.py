@@ -33,25 +33,27 @@ def _recorder():
     return mod.Recorder()
 
 
-def _faulted(tracer=None, fused=True, **kw):
+def _faulted(tracer=None, online=True, **kw):
+    """The crash scenario; the static loop (``online=False``) cannot
+    re-assign the crashed nodes' work, so it runs non-strict."""
     fi = FaultInjector(crash_at=CRASH, p_fail=0.15, seed=4)
     return _scenario(faults=fi, rel_k=1.0, max_attempts=8, tracer=tracer,
-                     fused=fused, noise_seed=3, **kw)
+                     online=online, strict=online, noise_seed=3, **kw)
 
 
 def _dump(trace) -> str:
     return json.dumps(trace.to_dict(), sort_keys=True)
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_fault_spans_leave_the_run_bit_identical(fused):
+@pytest.mark.parametrize("online", [False, True])
+def test_fault_spans_leave_the_run_bit_identical(online):
     """Schedule, observations, censored runs, counters and makespan are
     the same with no tracer, the null tracer, the benchmark's span
     recorder and a collecting log."""
-    base = _faulted(None, fused).run()
+    base = _faulted(None, online).run()
     assert base.failures > base.lost_nodes > 0     # the path was taken
     for tracer in (NULL_TRACER, _recorder(), EventLog()):
-        got = _faulted(tracer, fused).run()
+        got = _faulted(tracer, online).run()
         assert _dump(got) == _dump(base), type(tracer).__name__
 
 
@@ -63,10 +65,10 @@ class _PlanCallers(OnlineExecutor):
         return super()._plan(*args, **kw)
 
 
-def _counted(fused):
+def _counted(online=True):
     """(plan calls by caller, the run's trace, its span events)."""
     log = EventLog()
-    ex = _faulted(log, fused)
+    ex = _faulted(log, online)
     ex.__class__ = _PlanCallers
     ex.callers = Counter()
     return ex.callers, ex.run(), log
@@ -76,9 +78,9 @@ def _wall(e):
     return e.t_wall, e.t_wall + e.data["dur_s"]
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_fault_spans_count_the_lost_nodes_and_attempts(fused):
-    _, trace, log = _counted(fused)
+@pytest.mark.parametrize("online", [False, True])
+def test_fault_spans_count_the_lost_nodes_and_attempts(online):
+    _, trace, log = _counted(online)
     faults = log.spans("fault")
     attempts = sum(c.reason == "attempt" for c in trace.censored)
     assert attempts > 0 and trace.lost_nodes == len(CRASH)
@@ -88,12 +90,11 @@ def test_fault_spans_count_the_lost_nodes_and_attempts(fused):
     assert all(e.data["parent"] is None for e in faults)
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_replan_spans_count_fault_replans_only(fused):
+def test_replan_spans_count_fault_replans_only():
     """One ``replan`` span per re-plan of the fault path, none for the
     initial plan or a surprise re-plan, which keep their ``plan`` span
     alone; each holds its ``plan`` and lies inside its ``fault``."""
-    callers, trace, log = _counted(fused)
+    callers, trace, log = _counted()
     fault_replans = callers["replan_frontier"]
     surprise_replans = callers["run"] - 1           # less the initial plan
     assert fault_replans > 0 and surprise_replans > 0
@@ -114,11 +115,11 @@ def test_replan_spans_count_fault_replans_only(fused):
                    for p0, p1 in map(_wall, plans)) == 1
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_fault_free_run_enters_no_fault_span(fused):
+@pytest.mark.parametrize("online", [False, True])
+def test_fault_free_run_enters_no_fault_span(online):
     for kw in ({}, {"rel_k": 1.0}):
         rec = _recorder()
-        trace = _scenario(tracer=rec, fused=fused, **kw).run()
+        trace = _scenario(tracer=rec, online=online, **kw).run()
         assert trace.failures == trace.lost_nodes == 0
         phases = {s[0] for s in rec.spans}
         assert "plan" in phases

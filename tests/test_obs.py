@@ -19,10 +19,10 @@ from repro.sched.simulator import FaultInjector
 from tests.test_faults import _scenario
 
 
-def _faulty(tracer=None, **kw):
+def _faulty(tracer=None, online=True, **kw):
     fi = FaultInjector(p_fail=0.15, seed=3,
                        outages={"tpu-v2/0": (20.0, 120.0)})
-    return _scenario(online=True, faults=fi, rel_k=0.5, strict=False,
+    return _scenario(online=online, faults=fi, rel_k=0.5, strict=False,
                      tracer=tracer, noise_seed=7, slow=2.5,
                      spec_tail=0.8, **kw)
 
@@ -30,24 +30,24 @@ def _faulty(tracer=None, **kw):
 # ---------------------------------------------------------------------------
 # tracing is read-only: attaching a tracer never perturbs the loop
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("fused", [False, True])
-def test_tracer_disabled_is_bit_exact(fused):
+@pytest.mark.parametrize("online", [False, True])
+def test_tracer_disabled_is_bit_exact(online):
     """The PR 5 contract, extended: the executor's full output — every
     counter, record, censored run and observation, via ``to_dict`` — is
     bit-identical whether no tracer, the NULL_TRACER, or a collecting
-    ``EventLog`` is attached, on the legacy and the fused tick alike.
-    Tracing observes; it never steers."""
-    base = _faulty(tracer=None, fused=fused).run().to_dict()
+    ``EventLog`` is attached, on the static loop and the engine's tick
+    alike.  Tracing observes; it never steers."""
+    base = _faulty(tracer=None, online=online).run().to_dict()
     for tracer in (NULL_TRACER, EventLog()):
-        got = _faulty(tracer=tracer, fused=fused).run().to_dict()
+        got = _faulty(tracer=tracer, online=online).run().to_dict()
         assert json.dumps(got, sort_keys=True) == \
             json.dumps(base, sort_keys=True)
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_tracer_bit_exact_fault_free(fused):
-    a = _scenario(online=True, tracer=None, fused=fused).run().to_dict()
-    b = _scenario(online=True, tracer=EventLog(), fused=fused).run().to_dict()
+@pytest.mark.parametrize("online", [False, True])
+def test_tracer_bit_exact_fault_free(online):
+    a = _scenario(online=online, tracer=None).run().to_dict()
+    b = _scenario(online=online, tracer=EventLog()).run().to_dict()
     assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
 
 
@@ -76,23 +76,23 @@ def test_traced_run_emits_lifecycle_events():
     assert c["speculation"] == trace.speculations
     assert c["finish"] == trace.completed
     assert c.get("node_down", 0) >= 1 and c.get("node_up", 0) >= 1
-    # estimator + plan spans were recorded
-    assert log.spans("predict_matrix") and log.spans("plan")
-    assert log.spans("update_stream") and log.spans("bias_update")
+    # the engine's tick and plan spans were recorded
+    assert log.spans("tick_step") and log.spans("plan")
+    assert log.spans("tick_fetch") and log.spans("bias_fetch")
     # sim clock on events is monotone within the heap's pop order
     ticks = [e.t_sim for e in log.filter("tick")]
     assert all(a <= b + 1e-9 for a, b in zip(ticks, ticks[1:]))
 
 
 def test_fused_run_spans_inside_the_tick():
-    """A traced fused run splits each completion tick into its surprise
+    """A traced online run splits each completion tick into its surprise
     gate, the engine's tick (with the estimates' fetch nested in it) and
     the bias statistics' fetch; every span records its parent and the
     thread's CPU time over it.  The fault path nests too: a frontier
     re-plan inside the fault that caused it (or at top level after a
     rejoin), its HEFT plan inside the re-plan."""
     log = EventLog()
-    _faulty(tracer=log, fused=True).run()
+    _faulty(tracer=log).run()
     ticks = len(log.filter("predict"))          # one per completion tick
     assert ticks > 0
     for phase in ("surprise_gate", "tick_step", "tick_fetch", "bias_fetch"):
@@ -113,6 +113,20 @@ def test_fused_run_spans_inside_the_tick():
             st.t_wall + st.data["dur_s"] + 1e-9
     gates = log.spans("surprise_gate")
     assert sum(e.data["n"] for e in gates) == len(log.filter("observe"))
+
+
+def test_executor_has_one_tick_path():
+    """``fused=False`` is refused, and a default online run enters one
+    ``tick_step`` span per completion tick."""
+    with pytest.raises(ValueError, match="host per-tick path was removed"):
+        _scenario(fused=False)
+    log = EventLog()
+    trace = _scenario(tracer=log).run()
+    ticks = len(log.filter("predict"))          # one per completion tick
+    assert 0 < ticks <= trace.completed
+    assert len(log.spans("tick_step")) == ticks
+    assert sum(e.data["n"] for e in log.spans("tick_step")) == \
+        trace.completed
 
 
 def test_span_parent_stack_and_cpu_time():
@@ -379,10 +393,10 @@ def test_profiling_on_real_trace():
     log = EventLog()
     _scenario(online=True, tracer=log).run()
     s = tick_latency_summary(log.events)
-    assert set(s["phases"]) >= {"predict_matrix", "update_stream",
-                                "bias_update"}
+    assert set(s["phases"]) >= {"surprise_gate", "tick_step", "tick_fetch",
+                                "bias_fetch"}
     assert 0.0 < s["compile_frac"] <= 1.0
-    pm = s["phases"]["predict_matrix"]
+    pm = s["phases"]["tick_step"]
     # the first/steady split is present and self-consistent (whether the
     # first call actually compiled depends on the process's jit cache —
     # under `pytest -x` earlier tests may already have warmed it)

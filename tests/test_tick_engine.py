@@ -9,9 +9,11 @@ is algorithmic identity, not float32 noise — via a module fixture that
 flips ``jax_enable_x64`` and clears every jit cache on both edges.
 
 The executor-level spine drives all five paper workflows, faults off
-AND on, through a fused and a legacy executor built from identical
-seeds, and requires the full trace signatures (assignment, start/end,
-dispatch-time predictions, replan/surprise counters) to agree.
+AND on, through the executor as it runs (on a ``TickEngine``) and through
+the same executor handed the estimator's host path in the engine's
+place, built from identical seeds, and requires the full trace
+signatures (assignment, start/end, dispatch-time predictions,
+replan/surprise counters) to agree.
 """
 import jax
 import numpy as np
@@ -21,6 +23,7 @@ from repro.core import (LotaruEstimator, build_state, get_node,
                         profile_cluster, profile_node, target_nodes)
 from repro.core.tick import TickEngine, predict_state, tick_step
 from repro.online import OnlineExecutor, fanout_chain_dag
+from repro.online import executor as executor_mod
 from repro.sched.simulator import (ClusterSimulator, FaultInjector,
                                    GridEngine)
 from repro.sched.workflows import INPUTS, WORKFLOWS
@@ -415,8 +418,17 @@ def _trace_sig(trace):
                   trace.completed, trace.failures, trace.retries)
 
 
-def _run_workflow(cluster, wf, *, fused, with_faults, n_samples=2,
-                  nodes_per_type=2, seed=0):
+def _reference_engine(est, nodes, *, size, tracer=None):
+    """Test-local stand-in for ``TickEngine``: hands the executor the
+    estimator itself, so every tick takes the estimator's host path
+    (``observe_batch`` + dirty-row ``predict_matrix`` + the scalar
+    interval/PIT consumers) — the reference the engine is held to."""
+    est.finalize = lambda: None
+    return est
+
+
+def _run_workflow(cluster, wf, *, reference, with_faults, n_samples=2,
+                  nodes_per_type=2, seed=0, executor_cls=OnlineExecutor):
     local, local_bench, tbenches = cluster
     size = INPUTS[(wf, 1)]
     by_name = {t.name: t for t in WORKFLOWS[wf]}
@@ -429,23 +441,28 @@ def _run_workflow(cluster, wf, *, fused, with_faults, n_samples=2,
     grid = GridEngine.from_types(nodes_per_type=nodes_per_type)
     faults = (FaultInjector(p_fail=0.08, seed=seed + 31)
               if with_faults else None)
-    ex = OnlineExecutor(
-        est, tasks, task_name, size, grid,
-        lambda tid, node: truth_tab[(tid, grid.type_of(node).name)],
-        online=True, confidence=0.9, risk_k=0.5, spec_tail=0.6,
-        faults=faults, rel_k=1.0 if with_faults else None,
-        max_attempts=6, strict=False, fused=fused,
-        incremental_replan=fused if fused else False)
+    with pytest.MonkeyPatch.context() as mp:
+        if reference:
+            mp.setattr(executor_mod, "TickEngine", _reference_engine)
+        ex = executor_cls(
+            est, tasks, task_name, size, grid,
+            lambda tid, node: truth_tab[(tid, grid.type_of(node).name)],
+            online=True, confidence=0.9, risk_k=0.5, spec_tail=0.6,
+            faults=faults, rel_k=1.0 if with_faults else None,
+            max_attempts=6, strict=False)
     return ex.run()
 
 
 @pytest.mark.parametrize("wf", list(WORKFLOWS))
 @pytest.mark.parametrize("with_faults", [False, True])
 def test_fused_executor_matches_legacy(cluster, wf, with_faults):
-    legacy = _run_workflow(cluster, wf, fused=False,
+    """The engine executor against the same executor driving the
+    estimator's host reference path."""
+    legacy = _run_workflow(cluster, wf, reference=True,
                            with_faults=with_faults)
     jax.clear_caches()
-    fused = _run_workflow(cluster, wf, fused=True, with_faults=with_faults)
+    fused = _run_workflow(cluster, wf, reference=False,
+                          with_faults=with_faults)
     jax.clear_caches()
     recs_l, tail_l = _trace_sig(legacy)
     recs_f, tail_f = _trace_sig(fused)
@@ -457,25 +474,24 @@ def test_fused_executor_matches_legacy(cluster, wf, with_faults):
         np.testing.assert_allclose(b[2:], a[2:], rtol=TOL, atol=TOL)
 
 
+class _FromScratchRank(OnlineExecutor):
+    """Drops the rank cache before every re-plan, so each rank is built
+    from scratch over the full instance graph."""
+
+    def _incremental_rank(self, *args, **kw):
+        self._rank_cache = None
+        return super()._incremental_rank(*args, **kw)
+
+
 def test_incremental_replan_alone_is_bitwise(cluster):
-    base = _run_workflow(cluster, "methylseq", fused=False,
-                         with_faults=False)
+    """Incremental rank reuse against from-scratch ranking, both on the
+    estimator's reference path: the traces must be BITWISE equal, not
+    just 1e-12-close."""
+    inc = _run_workflow(cluster, "methylseq", reference=True,
+                        with_faults=False)
     jax.clear_caches()
-    # incremental rank reuse without the fused engine: same estimator
-    # path, so the traces must be BITWISE equal, not just 1e-12-close
-    local, local_bench, tbenches = cluster
-    wf, size = "methylseq", INPUTS[("methylseq", 1)]
-    by_name = {t.name: t for t in WORKFLOWS[wf]}
-    tasks, task_name = fanout_chain_dag(list(by_name), 2)
-    truth = ClusterSimulator(seed=2000)
-    truth_tab = {(tid, nt.name): truth.run_task(by_name[task_name[tid]],
-                                                nt, size)
-                 for tid in tasks for nt in target_nodes()}
-    est, _ = _fitted(cluster, wf, size)
-    grid = GridEngine.from_types(nodes_per_type=2)
-    inc = OnlineExecutor(
-        est, tasks, task_name, size, grid,
-        lambda tid, node: truth_tab[(tid, grid.type_of(node).name)],
-        online=True, confidence=0.9, risk_k=0.5, spec_tail=0.6,
-        max_attempts=6, strict=False, incremental_replan=True).run()
-    assert _trace_sig(inc) == _trace_sig(base)
+    scratch = _run_workflow(cluster, "methylseq", reference=True,
+                            with_faults=False,
+                            executor_cls=_FromScratchRank)
+    assert inc.replans > 0
+    assert _trace_sig(scratch) == _trace_sig(inc)
