@@ -19,8 +19,8 @@ from .estimator import (FittedCell, FittedTask, LotaruEstimator, LotaruML,
                         SCHEMA_VERSION, young_daly_interval)
 from .nodes import NODE_TYPES, NodeType, PAPER_ALIAS, get_node, target_nodes
 from .profiler import BenchResult, profile_cluster, profile_local, profile_node
-from .state import (EstimatorState, StateMeta, StateNames, bias_view,
-                    build_state, reliability_view, write_back)
+from .state import (EstimatorState, StateMeta, StateNames, build_state,
+                    reliability_view, write_back)
 from .tick import TickEngine, predict_state, tick_step
 
 __all__ = [
@@ -40,6 +40,6 @@ __all__ = [
     "young_daly_interval", "NODE_TYPES", "NodeType", "PAPER_ALIAS",
     "get_node", "target_nodes", "BenchResult", "profile_cluster",
     "profile_local", "profile_node", "EstimatorState", "StateMeta",
-    "StateNames", "bias_view", "build_state", "reliability_view",
+    "StateNames", "build_state", "reliability_view",
     "write_back", "TickEngine", "predict_state", "tick_step",
 ]

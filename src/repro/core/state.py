@@ -21,10 +21,10 @@ Design split, mirroring ``BatchedTaskModel``'s data/meta convention:
   node order, bias-column universe).  Deliberately OUTSIDE the pytree:
   strings never cross the device boundary.
 
-The OO classes stay the public API as thin *views* over this state —
-``bias_view`` / ``reliability_view`` rebuild bit-exact ``BiasModel`` /
-``ReliabilityModel`` objects from the leaves, and ``write_back``
-returns a mutated state into a live ``LotaruEstimator``.
+The OO classes stay the public API over this state —
+``reliability_view`` rebuilds a bit-exact ``ReliabilityModel`` from the
+leaves, and ``write_back`` returns a mutated state into a live
+``LotaruEstimator``.
 """
 from __future__ import annotations
 
@@ -35,7 +35,7 @@ import numpy as np
 from jax import numpy as jnp
 
 from .blr import (BatchedTaskModel, BiasModel, ReliabilityModel,
-                  _default_dtype)
+                  _default_dtype, slice_task_model)
 
 
 @dataclass(frozen=True)
@@ -150,19 +150,6 @@ def build_state(est, nodes, rel_nodes=()) -> tuple[EstimatorState,
                              rel_nodes=rel_nodes)
 
 
-def bias_view(state: EstimatorState) -> BiasModel:
-    """Rebuild the host ``BiasModel`` view of the state's bias leaves —
-    bit-exact: the sufficient statistics are copied at float64 and the
-    hyperparameters come from ``StateMeta``."""
-    m = state.meta
-    counts = np.asarray(state.bias_counts, np.float64)
-    return BiasModel(counts.shape[0], counts.shape[1], tau0=m.tau0,
-                     sigma_r=m.sigma_r, decay=m.decay,
-                     empirical_bayes=m.empirical_bayes, counts=counts,
-                     log_sum=np.asarray(state.bias_log_sum, np.float64),
-                     log_sq=np.asarray(state.bias_log_sq, np.float64))
-
-
 def reliability_view(state: EstimatorState,
                      names: StateNames) -> ReliabilityModel | None:
     """Rebuild the host ``ReliabilityModel`` view (``None`` while no
@@ -177,32 +164,48 @@ def reliability_view(state: EstimatorState,
                             state=seen)
 
 
+def scalar_part(model: BatchedTaskModel) -> BatchedTaskModel:
+    """The leaves a scalar ``TaskModel`` is sliced from — the Pearson
+    gate, the posterior and the median fallback — without the moments."""
+    return BatchedTaskModel(correlated=model.correlated, post=model.post,
+                            median=model.median, spread=model.spread)
+
+
 def write_back(state: EstimatorState, names: StateNames, est,
-               rows=None) -> None:
+               bias: BiasModel | None, rows=None) -> None:
     """Fold a mutated state back into a live ``LotaruEstimator`` so the
     legacy OO surface (scalar predicts, save/load, further
     ``observe_batch`` calls) continues from exactly where the fused tick
     left off.  ``rows`` limits the per-task scalar-model writeback to
     the rows the tick actually touched (the batch cache itself is always
-    swapped whole)."""
-    from .blr import slice_task_model
+    swapped whole).
 
-    model = state.model
+    The state crosses to the host once: one ``jax.device_get`` of
+    ``scalar_part(state.model)``, whose rows ``slice_task_model`` then
+    slices on the host — bitwise the rows it slices on the device, the
+    posterior leaves host arrays of the state's dtype.  ``bias`` is a
+    host ``BiasModel`` already holding the state's bias statistics at
+    float64 (``TickEngine``'s, from its last host view; ``None`` without
+    bias correction): the estimator takes copies of its arrays, and the
+    bias leaves are not read.  The reliability leaves are read only when
+    the state has reliability slots."""
     fts = [est.tasks[n] for n in names.tasks]
     w = np.array([ft.w for ft in fts], np.float64)
-    est._batch_cache = (list(names.tasks), fts, model, w)
+    est._batch_cache = (list(names.tasks), fts, state.model, w)
     touched = range(len(names.tasks)) if rows is None else sorted(rows)
-    for i in touched:
-        est.tasks[names.tasks[i]].model = slice_task_model(model, i)
+    if touched:
+        host = jax.device_get(scalar_part(state.model))
+        for i in touched:
+            est.tasks[names.tasks[i]].model = slice_task_model(host, i)
     est._mat_cache = None
     est._dirty_rows.clear()
     if est.bias_correction:
-        view = bias_view(state)
-        bias = est._ensure_bias()
-        bias.counts = view.counts
-        bias.log_sum = view.log_sum
-        bias.log_sq = view.log_sq
-        bias._sigma_r_cache = None
-    rel = reliability_view(state, names)
-    if rel is not None:
-        est.reliability = rel
+        into = est._ensure_bias()
+        into.counts = bias.counts.copy()
+        into.log_sum = bias.log_sum.copy()
+        into.log_sq = bias.log_sq.copy()
+        into._sigma_r_cache = None
+    if names.rel_nodes:
+        rel = reliability_view(state, names)
+        if rel is not None:
+            est.reliability = rel

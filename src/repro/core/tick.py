@@ -43,7 +43,8 @@ from jax import numpy as jnp
 
 from .blr import (BiasModel, _attach_log, _default_dtype, _predict_core,
                   _update_core_impl, predict_task_batch)
-from .state import EstimatorState, build_state, write_back
+from .state import (EstimatorState, build_state, scalar_part,
+                    write_back)
 
 
 def _sigma_r(meta, counts, log_sum, log_sq, dt):
@@ -472,7 +473,9 @@ class TickEngine:
     def finalize(self) -> None:
         """Fold the final state back into the wrapped estimator (batch
         cache, touched scalar models, bias posterior) — after this the
-        estimator's OO surface continues bit-compatibly."""
+        estimator's OO surface continues bit-compatibly.  One crossing
+        from the device, the touched rows' scalar models in one copy; the
+        bias statistics come from the engine's own host copy."""
         if self._rel_dirty:
             self._sync_reliability()
         state = EstimatorState(
@@ -483,5 +486,9 @@ class TickEngine:
             bias_log_sq=self.state.bias_log_sq,
             rel_succ=self.state.rel_succ, rel_fail=self.state.rel_fail,
             meta=self.state.meta)
-        write_back(state, self.names, self.est, rows=self._touched)
+        rows = sorted(self._touched)
+        nbytes = sum(a.nbytes for a in jax.tree.leaves(
+            scalar_part(self._model))) if rows else 0
+        with self.tracer.span("write_back", n=len(rows), nbytes=nbytes):
+            write_back(state, self.names, self.est, self._bias, rows)
         self._touched.clear()

@@ -129,6 +129,24 @@ def test_executor_has_one_tick_path():
         trace.completed
 
 
+@pytest.mark.parametrize("online", [False, True])
+def test_run_writes_back_once(online):
+    """An online run ends with one ``write_back`` span, after its last
+    tick, whose ``n`` is the task rows its observations touched; the
+    static loop ticks nothing and writes nothing back."""
+    log = EventLog()
+    trace = _faulty(tracer=log, online=online).run()
+    wb = log.spans("write_back")
+    if not online:
+        assert wb == []
+        return
+    wb, = wb
+    assert wb.data["parent"] is None
+    assert wb.data["n"] == len({o.task for o in trace.observations}) > 0
+    assert wb.data["nbytes"] > 0
+    assert max(e.t_wall for e in log.spans("tick_step")) < wb.t_wall
+
+
 def test_span_parent_stack_and_cpu_time():
     log = EventLog()
     with log.span("outer"):

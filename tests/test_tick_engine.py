@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from repro.core import (LotaruEstimator, build_state, get_node,
-                        profile_cluster, profile_node, target_nodes)
+                        profile_cluster, profile_node, slice_task_model,
+                        target_nodes)
 from repro.core.tick import TickEngine, predict_state, tick_step
 from repro.online import OnlineExecutor, fanout_chain_dag
 from repro.online import executor as executor_mod
@@ -382,6 +383,168 @@ def test_one_copy_per_tick(cluster, monkeypatch):
     fetch, = log.spans("tick_fetch")
     assert fetch.data["nbytes"] == views[0].nbytes == \
         (2 * t * n + 6 * t + 3 * t * nb) * 8
+
+
+#: three ticks that touch three of eager's rows, one twice
+_RUN = [[(0, 0, 30.0)], [(-1, 1, 12.0), (4, 2, 55.0)], [(-1, 1, 16.0)]]
+
+
+def _ticked_engine(cluster, tracer=None):
+    """An engine on a fitted eager estimator after ``_RUN``'s ticks and
+    two recorded attempts."""
+    wf, size = "eager", INPUTS[("eager", 1)]
+    est, by_name = _fitted(cluster, wf, size)
+    nodes = [nt.name for nt in target_nodes()]
+    names = list(by_name)
+    engine = TickEngine(est, nodes, size=size, tracer=tracer)
+    for batch in _RUN:
+        engine.observe_batch([(names[i], nodes[j], size, rt)
+                              for i, j, rt in batch])
+    engine.record_attempt(nodes[0], True)
+    engine.record_attempt(nodes[1], False)
+    return engine, names, nodes, size
+
+
+def test_finalize_copies_once(cluster, monkeypatch):
+    """``finalize`` folds the final state into the estimator with one
+    copy from the device, one ``jax.device_get``, whose byte count the
+    ``write_back`` span carries, and no eager device primitive: the
+    scalar models are sliced on the host, the bias statistics come from
+    the engine's host copy, and the engine, which has no reliability
+    slots, reads none.  The CPU backend does not enforce the transfer
+    guard, so reads of a device array's value outside that copy are
+    counted."""
+    from jax._src import array, dispatch
+    from repro.obs import EventLog
+    log = EventLog()
+    engine, _names, _nodes, _size = _ticked_engine(cluster, tracer=log)
+    touched = sorted(engine._touched)
+    ran, copied, implicit, inside = [], [], [], []
+    real_apply, real_get = dispatch.apply_primitive, jax.device_get
+    real_value = array.ArrayImpl._value
+
+    def counted(prim, *args, **kw):
+        ran.append(prim)
+        return real_apply(prim, *args, **kw)
+
+    def device_get(x):
+        copied.append(x)
+        inside.append(True)
+        try:
+            return real_get(x)
+        finally:
+            inside.pop()
+
+    def value(self):
+        if not inside:
+            implicit.append(self.shape)
+        return real_value.fget(self)
+
+    monkeypatch.setattr(dispatch, "apply_primitive", counted)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(array.ArrayImpl, "_value", property(value))
+    with jax.transfer_guard_device_to_host("disallow"):
+        engine.finalize()
+    monkeypatch.undo()
+    assert ran == [] and implicit == []
+    assert len(copied) == 1
+    wb, = log.spans("write_back")
+    assert wb.data["parent"] is None
+    assert wb.data["n"] == len(touched) == 3
+    assert wb.data["nbytes"] == sum(a.nbytes
+                                    for a in jax.tree.leaves(copied[0]))
+
+
+def _device_write_back(model, names, est, rows, counts, log_sum, log_sq):
+    """The write-back done on the device arrays: each touched row's scalar
+    model sliced from the device model, the bias statistics read from the
+    state's leaves and widened."""
+    fts = [est.tasks[n] for n in names]
+    est._batch_cache = (list(names), fts, model,
+                        np.array([ft.w for ft in fts], np.float64))
+    for i in rows:
+        est.tasks[names[i]].model = slice_task_model(model, i)
+    est._mat_cache = None
+    est._dirty_rows.clear()
+    bias = est._ensure_bias()
+    bias.counts, bias.log_sum, bias.log_sq = (
+        np.array(a, np.float64) for a in (counts, log_sum, log_sq))
+    bias._sigma_r_cache = None
+
+
+def _task_bits(m):
+    post = [_bits(np.asarray(getattr(m.post, f)))
+            for f in ("mu", "V", "a", "b", "x_scale", "y_scale")]
+    return bool(m.correlated), m.median, m.spread, post
+
+
+def _same_estimators(a, b, names, nodes, size):
+    """Every scalar model, bias statistic, reliability count and answer
+    of the two estimators is bitwise the same."""
+    for n in names:
+        assert _task_bits(a.tasks[n].model) == _task_bits(b.tasks[n].model)
+    for f in ("counts", "log_sum", "log_sq"):
+        assert _bits(getattr(a.bias, f)) == _bits(getattr(b.bias, f))
+    assert a.reliability.to_dict() == b.reliability.to_dict()
+    for n in names:
+        for nt in nodes:
+            assert a.predict(n, nt, size) == b.predict(n, nt, size)
+            assert a.predict_interval_node(n, nt, size, 0.9) == \
+                b.predict_interval_node(n, nt, size, 0.9)
+            assert a.predict_pit_node(n, nt, size, 30.0) == \
+                b.predict_pit_node(n, nt, size, 30.0)
+    ma, sa = a.predict_matrix(nodes, size)
+    mb, sb = b.predict_matrix(nodes, size)
+    assert _bits(ma) == _bits(mb) and _bits(sa) == _bits(sb)
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+def test_finalize_is_bitwise_the_device_write_back(cluster, request,
+                                                   tmp_path, dtype):
+    """After ``finalize`` the estimator is bitwise what slicing the final
+    state's device arrays gives: each touched row's scalar model is
+    ``slice_task_model`` of the state's model, the bias statistics the
+    state's leaves at float64, the reliability counts those
+    ``record_attempt`` wrote.  So are its answers after a further host
+    ``observe_batch``, a save and load, and a re-stacked batch."""
+    import copy
+    from repro.core.blr import _attach_log
+    if dtype == "float32":
+        request.getfixturevalue("_f32")
+    engine, names, nodes, size = _ticked_engine(cluster)
+    est = engine.est
+    model, st = engine._model, engine.state
+    assert st.factors.dtype == np.dtype(dtype)
+    rows = sorted(engine._touched)
+    est._batch_cache = None     # the first tick consumed its buffers
+    ref = copy.deepcopy(est)
+    _device_write_back(_attach_log(model, model.stats.log.copy()), names,
+                       ref, rows, st.bias_counts, st.bias_log_sum,
+                       st.bias_log_sq)
+    engine.finalize()
+    for i in rows:
+        assert _task_bits(est.tasks[names[i]].model) == \
+            _task_bits(slice_task_model(model, i))
+    _same_estimators(est, ref, names, nodes, size)
+
+    batch = [(names[0], nodes[2], size, 41.0),
+             (names[7], nodes[3], size, 9.0)]
+    assert est.observe_batch(batch) == ref.observe_batch(batch)
+    _same_estimators(est, ref, names, nodes, size)
+
+    est.save(tmp_path / "est.json")
+    ref.save(tmp_path / "ref.json")
+    assert (tmp_path / "est.json").read_text() == \
+        (tmp_path / "ref.json").read_text()
+    loaded = LotaruEstimator.load(tmp_path / "est.json")
+    assert loaded.predict(names[0], nodes[2], size) == \
+        est.predict(names[0], nodes[2], size)
+
+    for e in (est, ref):
+        e._batch_cache = None
+    got, want = est._batched()[1], ref._batched()[1]
+    assert [_bits(np.asarray(x)) for x in jax.tree.leaves(got)] == \
+        [_bits(np.asarray(x)) for x in jax.tree.leaves(want)]
 
 
 def test_tick_step_donates_and_predict_state_matches(cluster):
